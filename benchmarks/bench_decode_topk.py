@@ -50,6 +50,7 @@ from repro.core.hashing import inverted_table
 from repro.kernels import ops, ref
 from repro.kernels.mach_candidates import bucket_topm, candidate_chunks
 from repro.kernels.mach_topk import mach_topk_pallas
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCH_FILE = "BENCH_decode.json"   # regression-gated by benchmarks/run.py
 
@@ -300,6 +301,7 @@ def main() -> int:
                     help="small sweep (CI)")
     ap.add_argument("--out", default=BENCH_FILE)
     args = ap.parse_args()
+    enable_compile_cache()
     result = bench(quick=args.quick,
                    report=lambda n, us, d="": print(f"{n},{us:.2f},{d}",
                                                     flush=True))

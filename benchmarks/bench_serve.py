@@ -51,6 +51,7 @@ from repro.kernels import ops
 from repro.models import LanguageModel, ModelConfig
 from repro.serving import Request, ServeConfig, ServingEngine
 from repro.serving.engine import make_serve_step_fn
+from repro.launch.compile_cache import enable_compile_cache
 
 VOCAB = 4096                   # distinctive V for the jaxpr scan
 SLOTS = 4
@@ -386,6 +387,7 @@ def main() -> int:
     ap.add_argument("--quick", action="store_true", help="small workload (CI)")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    enable_compile_cache()
     result = bench(quick=args.quick,
                    report=lambda n, us, d="": print(f"{n},{us:.2f},{d}",
                                                     flush=True))

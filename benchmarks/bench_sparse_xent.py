@@ -49,6 +49,7 @@ import numpy as np
 from benchmarks.common import intermediate_avals, make_csr_case, timeit
 from repro.kernels import ops, ref
 from repro.kernels.mach_fused_xent import GATHER_NNZ_THRESHOLD
+from repro.launch.compile_cache import enable_compile_cache
 
 # (N, d, R, B, nnz_max): the first three share (N, R, B, nnz) and sweep
 # d only — the d-independence claim; the fourth is an ODP-like head
@@ -186,6 +187,7 @@ def main() -> int:
                     help="small sweep (CI)")
     ap.add_argument("--out", default="BENCH_sparse.json")
     args = ap.parse_args()
+    enable_compile_cache()
     result = bench(smoke=args.smoke,
                    report=lambda n, us, d="": print(f"{n},{us:.2f},{d}",
                                                     flush=True))

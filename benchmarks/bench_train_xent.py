@@ -71,6 +71,7 @@ from repro.kernels.mach_fused_xent import (DEFAULT_VMEM_BUDGET,
                                            choose_fused_blocks,
                                            dense_tile_bytes,
                                            mach_fused_xent_pallas)
+from repro.launch.compile_cache import enable_compile_cache
 
 # (N, d, R, B): acceptance config, paper's ODP (R=25, B=32) and
 # ImageNet-21k (R=20, B=512) heads, and a 32k-column ODP-scale head
@@ -409,6 +410,7 @@ def main() -> int:
     ap.add_argument("--quick", action="store_true", help="small sweep (CI)")
     ap.add_argument("--out", default="BENCH_xent.json")
     args = ap.parse_args()
+    enable_compile_cache()
     result = bench(quick=args.quick,
                    report=lambda n, us, d="": print(f"{n},{us:.2f},{d}",
                                                     flush=True))

@@ -31,6 +31,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _report(name: str, us_per_call: float, derived: str = "") -> None:
     print(f"{name},{us_per_call:.2f},{derived}", flush=True)
@@ -41,6 +43,7 @@ def main() -> int:
     ap.add_argument("--only", nargs="*", default=None,
                     help="subset of benchmark module names")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (bench_decode_topk, bench_kernels, bench_serve,
                             bench_sparse_xent, bench_train_xent,

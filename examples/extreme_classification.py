@@ -28,19 +28,15 @@ from repro.configs.odp_mach import IMAGENET, ODP
 from repro.core import MACHLinear
 from repro.data import (ExtremeDataConfig, ExtremeDataset,
                         SparseExtremeDataset)
-from repro.optim import adamw, apply_updates
+from repro.optim import adamw
+from repro.train.trainer import make_head_step
 
 
 def train(ds, model, params, steps=150, bs=512, lr=0.05, format=None):
     opt = adamw(lr)
     state = opt.init(params)
-
-    @jax.jit
-    def step(params, state, x, y):
-        loss, g = jax.value_and_grad(model.loss)(params, x, y)
-        upd, state = opt.update(g, state, params)
-        return apply_updates(params, upd), state, loss
-
+    step = make_head_step(model.loss, opt)
+    params = jax.tree.map(jnp.copy, params)     # the step donates them
     for s in range(steps):
         if format is None:
             x, y = ds.batch_at(s, bs)

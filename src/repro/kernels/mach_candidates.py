@@ -60,8 +60,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.estimators import ESTIMATORS
-from repro.kernels.mach_decode import NEG_INF, round_up
-from repro.kernels.mach_topk import _LANE, _merge_topk
+from repro.kernels.mach_decode import LANE as _LANE
+from repro.kernels.mach_decode import (NEG_INF, gather_rep, median_of,
+                                       merge_topk, round_up)
 
 # Penalty subtracted from backfill (count < t) candidate scores.  Every
 # estimator score lies in (-1/(B-1), 1], so subtracting OFFSET maps the
@@ -116,25 +117,27 @@ def bucket_topm(meta_probs: jnp.ndarray, m: int
     return jnp.min(tv, axis=-1).astype(jnp.float32), ti.astype(jnp.int32)
 
 
-def _topm_body(r: int, b: int, m: int, mpad: int,
-               probs_ref, ids_out, tau_out):
-    """Iterative max-extract: m rounds of (max, argmax, mask) per
-    repetition.  Reproduces lax.top_k's lowest-index tie order (argmax
-    takes the first maximum; masking removes exactly that column)."""
-    p = probs_ref[...].reshape(r, b).astype(jnp.float32)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (r, b), 1)
-    cols = []
-    tau = None
-    for _ in range(m):
-        v = jnp.max(p, axis=-1, keepdims=True)                 # (r, 1)
-        i = jnp.argmax(p, axis=-1, keepdims=True).astype(jnp.int32)
-        cols.append(i)
-        p = jnp.where(iota_b == i, NEG_INF, p)
-        tau = v
-    if mpad > m:
-        cols.append(jnp.zeros((r, mpad - m), jnp.int32))
-    ids_out[...] = jnp.concatenate(cols, axis=-1).reshape(1, r * mpad)
-    tau_out[...] = tau.reshape(1, r)
+def _topm_body(m: int, probs_ref, ids_out, tau_out):
+    """Iterative max-extract: m rounds of (max, first argmax, mask) per
+    repetition.  Reproduces lax.top_k's lowest-index tie order (the
+    first maximum is taken; masking removes exactly that column)."""
+    p = probs_ref[0].astype(jnp.float32)                       # (R, B)
+    lane = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
+    lane_m = jax.lax.broadcasted_iota(jnp.int32, ids_out.shape[1:], 1)
+
+    def extract(j, carry):
+        p, ids, _ = carry
+        v = jnp.max(p, axis=1, keepdims=True)                  # (R, 1)
+        i = jnp.min(jnp.where(p == v, lane, p.shape[1]), axis=1,
+                    keepdims=True)
+        return (jnp.where(lane == i, -jnp.inf, p),
+                jnp.where(lane_m == j, i, ids), v)
+
+    _, ids, tau = jax.lax.fori_loop(
+        0, m, extract, (p, jnp.zeros(ids_out.shape[1:], jnp.int32),
+                        jnp.zeros(tau_out.shape[1:], jnp.float32)))
+    ids_out[0] = ids
+    tau_out[0] = tau
 
 
 def bucket_topm_pallas(meta_probs: jnp.ndarray, m: int,
@@ -151,16 +154,16 @@ def bucket_topm_pallas(meta_probs: jnp.ndarray, m: int,
         raise ValueError(f"need 1 <= m <= B, got m={m}, B={b}")
     mpad = round_up(m, _LANE)
     ids, tau = pl.pallas_call(
-        functools.partial(_topm_body, r, b, m, mpad),
+        functools.partial(_topm_body, m),
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, r * b), lambda i: (i, 0))],
-        out_specs=(pl.BlockSpec((1, r * mpad), lambda i: (i, 0)),
-                   pl.BlockSpec((1, r), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((n, r * mpad), jnp.int32),
-                   jax.ShapeDtypeStruct((n, r), jnp.float32)),
+        in_specs=[pl.BlockSpec((1, r, b), lambda i: (i, 0, 0))],
+        out_specs=(pl.BlockSpec((1, r, mpad), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((1, r, 1), lambda i: (i, 0, 0))),
+        out_shape=(jax.ShapeDtypeStruct((n, r, mpad), jnp.int32),
+                   jax.ShapeDtypeStruct((n, r, 1), jnp.float32)),
         interpret=interpret,
-    )(meta_probs.reshape(n, r * b))
-    return tau, ids.reshape(n, r, mpad)[:, :, :m]
+    )(meta_probs)
+    return tau[:, :, 0], ids[:, :, :m]
 
 
 # ---------------------------------------------------------------------------
@@ -299,79 +302,66 @@ def mach_candidate_topk(meta_probs: jnp.ndarray,
 # Fused Pallas pipeline (inline multiply-shift mode).
 # ---------------------------------------------------------------------------
 
-def _cand_body(num_classes, r, b, m, ell, kcap, t, shift, estimator,
+def _cand_body(num_classes, r, b, m, k, t, shift, estimator,
                chunks_ref, coeffs_ref, meta_ref, tau_ref, inv_ref,
                val_out, idx_out, run_val, run_idx):
-    """Grid (n, R·m), chunk minor.  inv_ref is the (1, L) inverted-table
-    row for this chunk, DMA-selected by the scalar-prefetched chunk id;
-    meta_ref (1, R·B) and tau_ref (1, R) are row-resident in VMEM."""
+    """Grid (n, R·m), chunk minor.  inv_ref is the (1, 1, L)
+    inverted-table row for this chunk, DMA-selected by the
+    scalar-prefetched chunk id; meta_ref (1, R, B) and tau_ref (1, R, 1)
+    are row-resident in VMEM."""
     c = pl.program_id(1)
     nc = pl.num_programs(1)
 
     @pl.when(c == 0)
     def _init():
-        run_val[...] = jnp.full((1, kcap), NEG_INF, jnp.float32)
-        run_idx[...] = jnp.full((1, kcap), -1, jnp.int32)
+        run_val[...] = jnp.full(run_val.shape, NEG_INF, jnp.float32)
+        run_idx[...] = jnp.full(run_idx.shape, -1, jnp.int32)
 
-    pool = inv_ref[0, :]                                   # (L,) int32
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (ell, b), 1)
-    gs = []
-    for rr in range(r):
-        h = jax.lax.shift_right_logical(
-            pool.astype(jnp.uint32) * coeffs_ref[rr],
-            jnp.uint32(shift)).astype(jnp.int32)           # (L,)
-        onehot = (iota_b == h[:, None]).astype(jnp.float32)
-        meta_r = meta_ref[0, rr * b:(rr + 1) * b].astype(jnp.float32)
-        gs.append(jnp.dot(onehot, meta_r[:, None],
-                          preferred_element_type=jnp.float32))  # (L, 1)
-    g = jnp.concatenate(gs, axis=-1)                       # (L, R)
+    pool = inv_ref[0]                                      # (1, L) int32
+    ell = pool.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, ell), 0)
 
-    member = g >= tau_ref[0, :][None, :]
-    count = member.sum(-1)
-    first = jnp.argmax(member, -1)
+    def gather(rr, g):
+        # a loop, not an unrolled sweep: one repetition's one-hot slices
+        # are live at a time
+        h = jax.lax.shift_right_logical(pool * coeffs_ref[rr],
+                                        jnp.int32(shift))  # (1, L)
+        meta_r = meta_ref[0, pl.ds(rr, 1), :].astype(jnp.float32)
+        return jnp.where(row == rr, gather_rep(meta_r, h, b, ell), g)
+
+    g = jax.lax.fori_loop(0, r, gather, jnp.zeros((r, ell), jnp.float32))
+
+    member = g >= tau_ref[0]                               # (R, L)
+    count = jnp.sum(member.astype(jnp.int32), axis=0, keepdims=True)
+    first = jnp.min(jnp.where(member, row, r), axis=0, keepdims=True)
     claimed = (first == c // m) & (pool < num_classes)
     valid = claimed if t <= 1 else claimed & (count >= t)
 
     if estimator == "unbiased":
-        score = _affine_unbiased(jnp.mean(g, -1), b)
+        score = _affine_unbiased(jnp.mean(g, axis=0, keepdims=True), b)
     elif estimator == "min":
-        score = jnp.min(g, axis=-1)
+        score = jnp.min(g, axis=0, keepdims=True)
     else:
-        # odd-even transposition sort over the (static, small) R axis,
-        # then the two middle elements — matches jnp.median
-        for phase in range(r):
-            lo = phase % 2
-            for i in range(lo, r - 1, 2):
-                a, bb = g[:, i], g[:, i + 1]
-                g = g.at[:, i].set(jnp.minimum(a, bb))
-                g = g.at[:, i + 1].set(jnp.maximum(a, bb))
-        score = _median_sorted(g, axis=-1)
-
+        score = median_of([g[rr:rr + 1, :] for rr in range(r)])
     s_enc = jnp.where(claimed,
                       score - OFFSET * (1.0 - valid.astype(jnp.float32)),
                       NEG_INF)
 
-    width = max(ell, kcap)
-    if width > ell:
-        s_enc = jnp.concatenate(
-            [s_enc, jnp.full((width - ell,), NEG_INF, jnp.float32)])
-        pool = jnp.concatenate(
-            [pool, jnp.full((width - ell,), num_classes, jnp.int32)])
-    blk_val, blk_pos = jax.lax.top_k(s_enc[None, :], kcap)
-    blk_idx = jnp.take_along_axis(pool[None, :], blk_pos, axis=-1)
+    # skip the merge when no chunk entry can displace a kept one
+    lane = jax.lax.broadcasted_iota(jnp.int32, run_val.shape, 1)
+    kth = jnp.min(jnp.where(lane < k, run_val[...], jnp.inf))
 
-    # skip the merge sort when no chunk entry can displace a kept one
-    @pl.when(jnp.max(blk_val) > jnp.min(run_val[...]))
+    @pl.when(jnp.max(s_enc) > kth)
     def _merge():
-        new_val, new_idx = _merge_topk(run_val[...], run_idx[...],
-                                       blk_val, blk_idx, kcap)
+        new_val, new_idx = merge_topk(run_val[...], run_idx[...], s_enc,
+                                      pool, k)
         run_val[...] = new_val
         run_idx[...] = new_idx
 
     @pl.when(c == nc - 1)
     def _flush():
-        val_out[...] = run_val[...]
-        idx_out[...] = run_idx[...]
+        val_out[0] = run_val[...]
+        idx_out[0] = run_idx[...]
 
 
 def mach_candidate_topk_pallas(meta_probs: jnp.ndarray,
@@ -405,28 +395,31 @@ def mach_candidate_topk_pallas(meta_probs: jnp.ndarray,
     tau, ids = bucket_topm_pallas(meta_probs, m, interpret=interpret)
     chunks = candidate_chunks(ids, b)                      # (n, R·m)
 
+    # 3-D operands: every block's last two dims are whole array dims
+    row_spec = lambda shape: pl.BlockSpec(
+        shape, lambda i, c, chunks, coeffs: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n, r * m),
         in_specs=[
-            pl.BlockSpec((1, r * b), lambda i, c, chunks, coeffs: (i, 0)),
-            pl.BlockSpec((1, r), lambda i, c, chunks, coeffs: (i, 0)),
-            pl.BlockSpec((1, ell),
-                         lambda i, c, chunks, coeffs: (chunks[i, c], 0)),
+            row_spec((1, r, b)),
+            row_spec((1, r, 1)),
+            pl.BlockSpec((1, 1, ell),
+                         lambda i, c, chunks, coeffs: (chunks[i, c], 0, 0)),
         ],
-        out_specs=(pl.BlockSpec((1, kcap), lambda i, c, chunks, coeffs: (i, 0)),
-                   pl.BlockSpec((1, kcap), lambda i, c, chunks, coeffs: (i, 0))),
+        out_specs=(row_spec((1, 1, kcap)), row_spec((1, 1, kcap))),
         scratch_shapes=[pltpu.VMEM((1, kcap), jnp.float32),
                         pltpu.VMEM((1, kcap), jnp.int32)],
     )
+    coeffs = jax.lax.bitcast_convert_type(
+        inline_coeffs.astype(jnp.uint32), jnp.int32)
     val, idx = pl.pallas_call(
-        functools.partial(_cand_body, num_classes, r, b, m, ell, kcap, t,
+        functools.partial(_cand_body, num_classes, r, b, m, k, t,
                           inline_shift, estimator),
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((n, kcap), jnp.float32),
-                   jax.ShapeDtypeStruct((n, kcap), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct((n, 1, kcap), jnp.float32),
+                   jax.ShapeDtypeStruct((n, 1, kcap), jnp.int32)),
         interpret=interpret,
-    )(chunks, inline_coeffs.astype(jnp.uint32),
-      meta_probs.reshape(n, r * b), tau, inverted)
+    )(chunks, coeffs, meta_probs, tau[:, :, None], inverted[:, None, :])
 
-    return decode_penalty_topk(val[:, :k], idx[:, :k], t)
+    return decode_penalty_topk(val[:, 0, :k], idx[:, 0, :k], t)

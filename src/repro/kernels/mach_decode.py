@@ -1,17 +1,32 @@
-"""Fused MACH decode kernel (Algorithm 2 on the MXU).
+"""Fused streaming MACH decode (Algorithm 2 on the MXU): top-1 and top-k.
 
 The paper computes the global score matrix ``G[n, k] = Σ_r P_r[n, h_r(k)]``
 with an OpenCL gather kernel, materializes G (N×K), then argmaxes.  On
-TPU random gathers are VPU-bound, so we recast decode as a blocked
-matmul against a multi-hot matrix that is *built on the fly in VMEM*:
+TPU random gathers are VPU-bound, so decode is recast as blocked
+matmuls against one-hot bucket matrices that are *built on the fly in
+VMEM*, one repetition at a time:
 
-    G_tile = P_tile (bn, R·B)  @  M_tile (R·B, bk)
-    M[(r·B + b), k] = 1[h_r(k) = b]
+    g_r = P_r (bn, B)  @  M_r (B, bk),    M_r[b, k] = 1[h_r(k) = b]
 
-and we keep a *running* top-1 (value, index) accumulator in VMEM scratch
-across K blocks — the N×K score matrix never exists in HBM.  HBM traffic
-drops from O(N·K) to O(N·R·B + N) and the contraction (depth R·B) runs
-on the MXU.
+and the per-class estimator combines the R gathered values: their sum
+(unbiased, Eq. 2 — the affine map is applied after selection, it is
+monotone), their min (Eq. 7) or their median (Eq. 8, an odd-even
+transposition sort over the R values).  A running top-k (values, class
+ids) lives in VMEM scratch across K blocks — the N×K score matrix never
+exists in HBM.  HBM traffic is O(N·R·B + K·R [table mode] + N·k).
+
+Grid (N/bn, K/bk, R/nh): the repetition-chunk axis is minor, so each
+(row block, K block) cell sweeps nh repetitions per step.  Where the
+whole (R, bn, B) probability block fits the VMEM budget nh = R and the
+block stays resident across the K sweep; at retrieval widths
+(R·B = 131,072) the chooser tiles R instead, and B itself is swept in
+2048-bucket slices, so no tiling it returns overflows VMEM.
+
+Selection uses only ops that Mosaic lowers (no ``top_k``/``sort``): the
+running set and the block's scores are merged by k rounds of
+max-extract.  Each round takes the largest value and, among equal
+values, the lowest class id — ``lax.top_k``'s tie order on the full
+score matrix — then retires that id.
 
 Two hash sources:
   * table mode   — the (R, K) int32 bucket table is tiled in (works for
@@ -19,10 +34,6 @@ Two hash sources:
   * inline mode  — multiply-shift hashes are computed in-register from
                    the class index (paper §2.1's trick), removing the
                    table load from HBM entirely.  Requires B = 2^k.
-
-Grid: (N/bn, K/bk), K minor (innermost) so the scratch accumulator for a
-fixed N block sees all K blocks in order; the P tile's index map is
-K-invariant so Pallas keeps it resident in VMEM across the K sweep.
 """
 
 from __future__ import annotations
@@ -36,6 +47,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+LANE = 128                # running-top-k capacity granularity
+_B_SLICE = 2048           # buckets per one-hot slice (VMEM bound at big B)
+_ID_SENTINEL = jnp.iinfo(jnp.int32).max
+DECODE_VMEM_BUDGET = 6 * 2**20
 
 
 def round_up(x: int, m: int) -> int:
@@ -44,159 +59,259 @@ def round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def multihot_block(hash_ref, inline_shift, kbase, r, b, bk):
-    """(R, B, bk) one-hot bucket matrix built on the fly in VMEM.
+def decode_tile_bytes(bn: int, bk: int, nh: int, b: int, *,
+                      estimator: str = "unbiased", kcap: int = LANE,
+                      r: int = 0) -> int:
+    """Accounted VMEM bytes of one decode grid step (f32/i32 words):
 
-    M[r, b, k] = 1[h_r(kbase + k) = b], from either a tiled table slice
-    (hash_ref (r, bk) int32; ``inline_shift`` None) or inline
-    multiply-shift coefficients (hash_ref (r, 1) uint32).  Shared by the
-    top-1 and streaming top-k decode kernels.
-    """
-    if inline_shift is None:
-        buckets = hash_ref[...]                               # (r, bk)
-    else:
-        kk = (kbase + jax.lax.broadcasted_iota(jnp.int32, (r, bk), 1)
-              ).astype(jnp.uint32)
-        buckets = jax.lax.shift_right_logical(
-            hash_ref[...] * kk, jnp.uint32(inline_shift)).astype(jnp.int32)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (r, b, bk), 1)
-    return (iota_b == buckets[:, None, :]).astype(jnp.float32)
-
-
-def mask_k_tail(scores, kbase, num_classes, bn, bk):
-    """NEG_INF for the K padding tail (global class id >= K)."""
-    gidx = kbase + jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
-    return jnp.where(gidx < num_classes, scores, NEG_INF)
+    double-buffered probability block 2·(nh, bn, B) and hash block
+    2·(nh, 8, bk) (a (1, bk) row pads to a sublane tile); one one-hot
+    slice (min(B, 2048), bk) plus its mask; the gathered (bn, bk) value
+    and the (bn, bk) accumulator; min/median's padded (R, bn, bk) value
+    cube for the order statistic (median only) ; the running top-k
+    (bn, kcap) pair, its outputs, and the merge temporaries over
+    (bn, kcap + bk)."""
+    bs = min(b, _B_SLICE)
+    words = 2 * nh * bn * b + 2 * nh * 8 * bk + 2 * bs * bk + 2 * bn * bk
+    if estimator == "median":
+        words += round_up(r, nh) * bn * bk
+    words += 4 * bn * kcap + 4 * bn * (kcap + bk)
+    return 4 * words
 
 
-def _update_top1(scores, kbase, bn, run_val, run_idx, kblk, nk,
-                 val_out, idx_out):
-    """Shared running-top-1 logic.  scores: (bn, bk) f32."""
-    @pl.when(kblk == 0)
-    def _init():
-        run_val[...] = jnp.full((bn, 1), NEG_INF, jnp.float32)
-        run_idx[...] = jnp.zeros((bn, 1), jnp.int32)
-
-    blk_val = jnp.max(scores, axis=-1, keepdims=True)                 # (bn, 1)
-    blk_idx = jnp.argmax(scores, axis=-1, keepdims=True).astype(jnp.int32)
-    # strict > keeps the first global argmax (jnp.argmax tie-breaking)
-    better = blk_val > run_val[...]
-    run_val[...] = jnp.where(better, blk_val, run_val[...])
-    run_idx[...] = jnp.where(better, kbase + blk_idx, run_idx[...])
-
-    @pl.when(kblk == nk - 1)
-    def _flush():
-        val_out[...] = run_val[...]
-        idx_out[...] = run_idx[...]
+def _rep_chunks(r: int) -> list[int]:
+    """Candidate repetitions per step, descending: every distinct
+    ceil(R / c)."""
+    return sorted({-(-r // c) for c in range(1, r + 1)}, reverse=True)
 
 
-def _decode_body(num_classes, bn, bk, r, b, shift,
-                 probs_ref, hash_ref, val_out, idx_out,
-                 run_val, run_idx):
-    """One (n-block, k-block) step.  probs_ref: (bn, R·B) VMEM;
-    hash_ref: (R, bk) int32 table tile (``shift`` None) or (R, 1) uint32
-    multiply-shift coefficients (bucket = (a_r · k mod 2^32) >> shift —
-    no hash table in HBM)."""
-    kblk = pl.program_id(1)
-    nk = pl.num_programs(1)
-    kbase = kblk * bk
-
-    # Multi-hot flattened r-major to (R·B, bk) so one MXU matmul covers
-    # all R repetitions.
-    m = multihot_block(hash_ref, shift, kbase, r, b, bk)
-    scores = jnp.dot(probs_ref[...].astype(jnp.float32),
-                     m.reshape(r * b, bk),
-                     preferred_element_type=jnp.float32)              # (bn, bk)
-    scores = mask_k_tail(scores, kbase, num_classes, bn, bk)
-    _update_top1(scores, kbase, bn, run_val, run_idx, kblk, nk,
-                 val_out, idx_out)
-
-
-def decode_tile_bytes(bn: int, bk: int, rb: int, *, r: int = 0,
-                      estimator: str = "unbiased", kcap: int = 0) -> int:
-    """VMEM bytes one (bn, bk) decode tile needs, per estimator.
-
-    Always: P tile (bn, R·B) f32 + on-the-fly multi-hot M (R·B, bk) f32.
-    min/median additionally keep the per-repetition score cube
-    (R, bn, bk) f32 alive until the reduce (one matmul per repetition
-    instead of one over the flattened R·B axis).  ``kcap`` > 0 accounts
-    for the streaming top-k merge state: running (val, idx) pairs of
-    width kcap plus the sorted (bn, 2·kcap) concat temporaries.
-    """
-    nbytes = 4 * (bn * rb + rb * bk)
-    if estimator in ("min", "median"):
-        nbytes += 4 * r * bn * bk
-    if kcap:
-        nbytes += 4 * 2 * bn * (kcap + 2 * kcap)
-    return nbytes
-
-
-def choose_decode_blocks(n: int, rb: int,
+def choose_decode_blocks(n: int, r: int, b: int,
                          block_n: Optional[int] = None,
                          block_k: Optional[int] = None,
-                         vmem_budget: int = 6 * 2**20,
-                         *, r: int = 0, estimator: str = "unbiased",
-                         kcap: int = 0) -> tuple[int, int]:
-    """Pick (bn, bk) so ``decode_tile_bytes`` fits in ``vmem_budget``,
-    bk a multiple of 128 (lane width) for MXU alignment, first-fit
-    descending from 2048.
+                         vmem_budget: int = DECODE_VMEM_BUDGET,
+                         *, estimator: str = "unbiased",
+                         kcap: int = LANE) -> tuple[int, int, int]:
+    """Pick (bn, bk, nh): row block, K block (a lane multiple) and
+    repetitions per step — the first candidate whose
+    ``decode_tile_bytes`` fit ``vmem_budget``, keeping bk large first
+    (fewer K steps) and then nh (fewer repetition steps).
 
     bn is rounded up to a multiple of 8 (the fp32 sublane tile) whatever
-    the caller passes — an odd ``block_n`` would otherwise produce a
-    padded N that bn does not tile cleanly on TPU.  The kernels pad N up
-    to the returned bn, so any bn/bk combination stays correct.
-
-    Raises ValueError when even the (bn, 128) floor tile overflows the
-    budget — the caller should shrink bn/kcap or raise the budget
-    explicitly rather than silently overflow VMEM (an explicit
-    ``block_k`` skips the accounting entirely).
-    """
+    the caller passes; the kernel pads N up to it.  An explicit
+    ``block_k`` skips the accounting (the caller takes responsibility)
+    and keeps every repetition in one step.  Raises ValueError when even
+    (bn, 128, one repetition) overflows the budget."""
     bn = block_n or min(128, max(8, n))
     bn = max(8, round_up(bn, 8))
     if block_k is not None:
-        return bn, block_k
-    bk = 2048
-    while bk > 128 and decode_tile_bytes(
-            bn, bk, rb, r=r, estimator=estimator, kcap=kcap) > vmem_budget:
-        bk -= 128
-    bk = max(bk, kcap and round_up(kcap, 128))
-    if decode_tile_bytes(bn, bk, rb, r=r, estimator=estimator,
-                         kcap=kcap) > vmem_budget:
-        raise ValueError(
-            f"decode tile does not fit: bn={bn} bk={bk} rb={rb} r={r} "
-            f"estimator={estimator!r} kcap={kcap} needs "
-            f"{decode_tile_bytes(bn, bk, rb, r=r, estimator=estimator, kcap=kcap)}"
-            f" bytes > vmem_budget={vmem_budget}; pass block_k to override")
-    return bn, bk
+        return bn, block_k, r
+    for bk in (2048, 1024, 512, 256, 128):
+        for nh in _rep_chunks(r):
+            if decode_tile_bytes(bn, bk, nh, b, estimator=estimator,
+                                 kcap=kcap, r=r) <= vmem_budget:
+                return bn, bk, nh
+    need = decode_tile_bytes(bn, 128, 1, b, estimator=estimator, kcap=kcap,
+                             r=r)
+    raise ValueError(
+        f"decode tile does not fit: bn={bn} bk=128 nh=1 r={r} b={b} "
+        f"estimator={estimator!r} kcap={kcap} needs {need} bytes > "
+        f"vmem_budget={vmem_budget}; pass block_k to override")
 
 
-def prepare_decode_operands(meta_probs, table, num_classes, inline_coeffs,
-                            inline_shift, bn, bk, k_grid):
-    """Shared host-side setup for the top-1 and streaming top-k kernels.
+def _bucket_row(hash_ref, t, inline_shift, kbase, bk):
+    """(1, bk) bucket ids of repetition slot t for classes kbase + [0,
+    bk): a tiled table row, or multiply-shift hashing in int32 (the
+    wrapping product has the same bits as the uint32 one)."""
+    if inline_shift is None:
+        return hash_ref[t]                                   # (1, bk)
+    kk = kbase + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    return jax.lax.shift_right_logical(hash_ref[t] * kk,
+                                       jnp.int32(inline_shift))
 
-    Validates the hash source, pads N up to bn and the table's K up to
-    the grid (pad bucket = B: all-zero one-hot columns), and returns
-    (probs2d (npad, R·B), npad, hash_arg, hash_spec, inline_shift) —
-    ``inline_shift`` is None in table mode.
-    """
+
+def gather_rep(probs, buckets, b, bk):
+    """g[n, k] = probs[n, buckets[k]] as one-hot matmuls over B slices
+    (a bucket id of B or more — table padding — gathers 0)."""
+    g = None
+    for b0 in range(0, b, _B_SLICE):
+        bs = min(_B_SLICE, b - b0)
+        onehot = (b0 + jax.lax.broadcasted_iota(jnp.int32, (bs, bk), 0)
+                  == buckets).astype(jnp.float32)
+        # f32 precision: the default would round probs to bf16
+        part = jnp.dot(probs if bs == b else probs[:, b0:b0 + bs], onehot,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+        g = part if g is None else g + part
+    return g
+
+
+def median_of(slabs):
+    """Elementwise median of a list of equal-shape arrays (jnp.median
+    semantics: mean of the two middle values for an even count), by an
+    odd-even transposition sort — compare-exchanges that Mosaic
+    lowers."""
+    v = list(slabs)
+    r = len(v)
+    for phase in range(r):
+        for i in range(phase % 2, r - 1, 2):
+            v[i], v[i + 1] = jnp.minimum(v[i], v[i + 1]), \
+                jnp.maximum(v[i], v[i + 1])
+    return (v[(r - 1) // 2] + v[r // 2]) * 0.5
+
+
+def merge_topk(run_val, run_idx, blk_val, blk_idx, k):
+    """Merge a running top-k (bn, kcap) with candidate scores (bn, w) by
+    k rounds of max-extract -> new (bn, kcap) running set.  Every id is
+    unique; among equal values the lowest id wins, so the result is
+    ``lax.top_k``'s order on the union.  Lanes k.. of the result hold
+    (NEG_INF, sentinel)."""
+    kcap = run_val.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, run_val.shape, 1)
+
+    def extract(j, carry):
+        rv, bv, out_v, out_i = carry
+        m = jnp.maximum(jnp.max(rv, axis=1, keepdims=True),
+                        jnp.max(bv, axis=1, keepdims=True))
+        pick = jnp.minimum(
+            jnp.min(jnp.where(rv == m, run_idx, _ID_SENTINEL), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(bv == m, blk_idx, _ID_SENTINEL), axis=1,
+                    keepdims=True))
+        out_v = jnp.where(lane == j, m, out_v)
+        out_i = jnp.where(lane == j, pick, out_i)
+        rv = jnp.where(run_idx == pick, -jnp.inf, rv)
+        bv = jnp.where(blk_idx == pick, -jnp.inf, bv)
+        return rv, bv, out_v, out_i
+
+    init = (run_val, blk_val, jnp.full(run_val.shape, NEG_INF, jnp.float32),
+            jnp.full(run_idx.shape, _ID_SENTINEL, jnp.int32))
+    _, _, out_v, out_i = jax.lax.fori_loop(0, min(k, kcap), extract, init)
+    return out_v, out_i
+
+
+def _topk_body(num_classes, r, b, bn, bk, nh, k, estimator, inline_shift,
+               probs_ref, hash_ref, val_out, idx_out, acc_scr, run_val,
+               run_idx, *cube):
+    """Grid (N/bn, K/bk, R/nh).  probs_ref (nh, bn, B); hash_ref (nh, 1,
+    bk) table slice or (nh, 1, 1) int32 multiply-shift coefficients;
+    cube = the (rp, bn, bk) median scratch."""
+    kblk = pl.program_id(1)
+    c = pl.program_id(2)
+    nc = pl.num_programs(2)
+    kbase = kblk * bk
+
+    @pl.when((kblk == 0) & (c == 0))
+    def _init_run():
+        run_val[...] = jnp.full(run_val.shape, NEG_INF, jnp.float32)
+        # distinct negative ids, so retiring one never retires another
+        run_idx[...] = -1 - jax.lax.broadcasted_iota(jnp.int32,
+                                                     run_idx.shape, 1)
+
+    @pl.when(c == 0)
+    def _init_acc():
+        fill = jnp.inf if estimator == "min" else 0.0
+        acc_scr[...] = jnp.full((bn, bk), fill, jnp.float32)
+
+    for t in range(nh):
+        rep = c * nh + t
+        g = gather_rep(probs_ref[t].astype(jnp.float32),
+                        _bucket_row(hash_ref, t, inline_shift, kbase, bk),
+                        b, bk)
+        if estimator == "unbiased":
+            acc_scr[...] += g          # padded repetitions gather 0
+        elif estimator == "min":
+            acc_scr[...] = jnp.minimum(acc_scr[...],
+                                       jnp.where(rep < r, g, jnp.inf))
+        else:
+            cube[0][rep] = g
+
+    @pl.when(c == nc - 1)
+    def _select():
+        if estimator == "median":
+            scores = median_of([cube[0][j] for j in range(r)])
+        else:
+            scores = acc_scr[...]
+        ids = kbase + jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
+        scores = jnp.where(ids < num_classes, scores, NEG_INF)
+        lane = jax.lax.broadcasted_iota(jnp.int32, run_val.shape, 1)
+        kth = jnp.min(jnp.where(lane < k, run_val[...], jnp.inf))
+
+        # Most K blocks of a selective decode hold no score above the
+        # running k-th; ties go to the running set (lower ids), so
+        # skipping on <= is exact.
+        @pl.when(jnp.max(scores) > kth)
+        def _merge():
+            v, i = merge_topk(run_val[...], run_idx[...], scores, ids, k)
+            run_val[...] = v
+            run_idx[...] = i
+
+        @pl.when(kblk == pl.num_programs(1) - 1)
+        def _flush():
+            val_out[...] = run_val[...]
+            idx_out[...] = run_idx[...]
+
+
+def streaming_topk(meta_probs: jnp.ndarray,
+                   table: Optional[jnp.ndarray] = None,
+                   *,
+                   num_classes: int,
+                   k: int,
+                   estimator: str = "unbiased",
+                   inline_coeffs: Optional[jnp.ndarray] = None,
+                   inline_shift: Optional[int] = None,
+                   block_n: Optional[int] = None,
+                   block_k: Optional[int] = None,
+                   interpret: bool = False
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """meta_probs (N, R, B) -> top-k (raw scores, class ids), each
+    (N, k).  Raw scores: the sum over repetitions (unbiased), the min
+    or the median.  Exactly one of ``table`` ((R, K) int32) or
+    (``inline_coeffs`` ((R,) uint32), ``inline_shift``) must be given."""
     n, r, b = meta_probs.shape
-    probs2d = meta_probs.reshape(n, r * b)
-    n_pad = -n % bn
-    if n_pad:
-        probs2d = jnp.pad(probs2d, ((0, n_pad), (0, 0)))
+    kcap = round_up(k, LANE)
+    bn, bk, nh = choose_decode_blocks(n, r, b, block_n, block_k,
+                                      estimator=estimator, kcap=kcap)
+    rp = round_up(r, nh)
+    npad = round_up(n, bn)
+    k_grid = pl.cdiv(num_classes, bk)
+    probs = jnp.pad(jnp.moveaxis(meta_probs, 1, 0),
+                    ((0, rp - r), (0, npad - n), (0, 0)))   # (rp, npad, B)
     if table is not None:
-        k_pad = k_grid * bk - num_classes
-        hash_arg = jnp.pad(table, ((0, 0), (0, k_pad)), constant_values=b)
-        hash_spec = pl.BlockSpec((r, bk), lambda i, j: (0, j))
+        # pad bucket = B: an all-zero one-hot column
+        hash_arg = jnp.pad(table.astype(jnp.int32),
+                           ((0, rp - r), (0, k_grid * bk - num_classes)),
+                           constant_values=b)[:, None, :]
+        hash_spec = pl.BlockSpec((nh, 1, bk), lambda i, j, c: (c, 0, j))
         inline_shift = None
     else:
         if inline_coeffs is None or inline_shift is None:
             raise ValueError("need table or (inline_coeffs, inline_shift)")
         if b & (b - 1):
             raise ValueError("inline mode requires power-of-two B")
-        hash_arg = inline_coeffs.reshape(r, 1)
-        hash_spec = pl.BlockSpec((r, 1), lambda i, j: (0, 0))
-    return probs2d, n + n_pad, hash_arg, hash_spec, inline_shift
+        coeffs = jax.lax.bitcast_convert_type(
+            inline_coeffs.astype(jnp.uint32), jnp.int32)
+        hash_arg = jnp.pad(coeffs, (0, rp - r)).reshape(rp, 1, 1)
+        hash_spec = pl.BlockSpec((nh, 1, 1), lambda i, j, c: (c, 0, 0))
+
+    scratch = [pltpu.VMEM((bn, bk), jnp.float32),
+               pltpu.VMEM((bn, kcap), jnp.float32),
+               pltpu.VMEM((bn, kcap), jnp.int32)]
+    if estimator == "median":
+        scratch.append(pltpu.VMEM((rp, bn, bk), jnp.float32))
+    out_spec = pl.BlockSpec((bn, kcap), lambda i, j, c: (i, 0))
+    val, idx = pl.pallas_call(
+        functools.partial(_topk_body, num_classes, r, b, bn, bk, nh, k,
+                          estimator, inline_shift),
+        grid=(npad // bn, k_grid, rp // nh),
+        in_specs=[pl.BlockSpec((nh, bn, b), lambda i, j, c: (c, i, 0)),
+                  hash_spec],
+        out_specs=(out_spec, out_spec),
+        out_shape=(jax.ShapeDtypeStruct((npad, kcap), jnp.float32),
+                   jax.ShapeDtypeStruct((npad, kcap), jnp.int32)),
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(probs, hash_arg)
+    return val[:n, :k], idx[:n, :k]
 
 
 def mach_decode_pallas(meta_probs: jnp.ndarray,
@@ -209,30 +324,10 @@ def mach_decode_pallas(meta_probs: jnp.ndarray,
                        block_k: Optional[int] = None,
                        interpret: bool = False
                        ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused top-1 decode.  meta_probs (N, R, B) -> (val (N,), idx (N,)).
-
-    Exactly one of ``table`` ((R, K) int32) or
-    (``inline_coeffs`` ((R,) uint32), ``inline_shift``) must be given.
-    """
-    n, r, b = meta_probs.shape
-    rb = r * b
-    bn, bk = choose_decode_blocks(n, rb, block_n, block_k)
-    k_grid = pl.cdiv(num_classes, bk)
-    probs2d, npad, hash_arg, hash_spec, shift = prepare_decode_operands(
-        meta_probs, table, num_classes, inline_coeffs, inline_shift, bn, bk,
-        k_grid)
-
-    val, idx = pl.pallas_call(
-        functools.partial(_decode_body, num_classes, bn, bk, r, b, shift),
-        grid=(npad // bn, k_grid),
-        in_specs=[pl.BlockSpec((bn, rb), lambda i, j: (i, 0)), hash_spec],
-        out_specs=(pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-                   pl.BlockSpec((bn, 1), lambda i, j: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((npad, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((npad, 1), jnp.int32)),
-        scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32),
-                        pltpu.VMEM((bn, 1), jnp.int32)],
-        interpret=interpret,
-    )(probs2d, hash_arg)
-
-    return val[:n, 0], idx[:n, 0]
+    """Fused top-1 decode under the summed-score rule (≡ unbiased
+    argmax).  meta_probs (N, R, B) -> (summed score (N,), class (N,))."""
+    val, idx = streaming_topk(
+        meta_probs, table, num_classes=num_classes, k=1,
+        inline_coeffs=inline_coeffs, inline_shift=inline_shift,
+        block_n=block_n, block_k=block_k, interpret=interpret)
+    return val[:, 0], idx[:, 0]

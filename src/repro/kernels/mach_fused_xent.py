@@ -32,18 +32,26 @@ trade) from the saved per-head logsumexp:
 
     dlogits[n, rB+b] = g_n · (softmax(logits)[n, r, b] − 1[b = y_nr])
 
-in a single kernel, grid (C/bc, N/bn, 2·D/bd): per (column, row) cell
-the d axis is swept twice.  Phase 1 (k2 < nkd) rebuilds the logits tile
-once — accumulating activation-slice @ W-tile products across d blocks
-— and at its last step forms dlogits into (bn, bc) scratch, reducing
-dbias into the revisited (1, bc) output row.  Phase 2 (k2 >= nkd)
-revisits the d blocks: ``dW_blk += a_kᵀ @ dlogits`` accumulates through
-the revisited (bd, bc) output window (initialized at the first row
-block, read-modify-written on later revisits) and — dense h only —
-``dh_k += dlogits @ W_kᵀ`` through a revisited (bn, bd) output block
-(initialized at the first column block).  Activation residuals are the
-inputs and the (N, R) logsumexp — O(N·d) dense / O(N·J) sparse,
-independent of R·B.
+in a single kernel, grid (C/bc, 2·rows/bn·D/bd) with two phases per
+column block.  Phase 1 sweeps the d blocks inside each row block,
+rebuilding its logits tile once; at the last d block it forms dlogits
+into a (rows, bc) VMEM scratch and reduces dbias into the (1, bc)
+output row.  Phase 2 sweeps the row blocks inside each d block:
+``dW_blk += a_kᵀ @ dlogits`` accumulates in the (bd, bc) output block
+over consecutive steps, and — dense h only — ``dh_blk = dlogits @
+W_kᵀ`` is written per column block (the wrapper sums the C/bc
+partials).  No output block is revisited after its index moved away: a
+TPU writes an output block back when its index changes and never
+re-reads it.  Batches beyond ``_BWD_ROWS`` rows run the backward once
+per row chunk.  Activation residuals are the inputs and the (N, R)
+logsumexp — O(N·d) dense / O(N·J) sparse, independent of R·B.
+
+The per-head reductions work on the 2-D (bn, bc) tile with one lane
+mask per head — never a (bn, nh, width) reshape, which splits lanes
+when width < 128 (ODP's B = 32) — and read and write the (bn, R)
+per-head labels and statistics through masked lane selects, since the
+first head of a block is a traced index.  f32 contractions run at f32
+precision (``_F32``).
 
 Sparse features (the paper's ODP d=422k workload): the ``*_sparse``
 entry points take the batch in padded-ELL form — ``cols/vals (N, J)``,
@@ -97,13 +105,11 @@ padded columns are masked to NEG_INF before the reduction and zeroed in
 the backward (so dbias's padded columns are zero too).  Sparse operands
 additionally pad J to a lane multiple (padded slots carry val 0).
 
-Interpret-mode caveat (see ROADMAP): the revisited accumulators rely on
-output blocks being re-fetched on non-consecutive revisits.  Every grid
-here is declared ``dimension_semantics=("arbitrary", ...)`` so Mosaic
-must execute steps sequentially (no parallel reordering across the
-revisited windows); interpret mode executes the re-fetch faithfully but
-cannot vet the native pipelining — validate on real TPU before flipping
-defaults.
+Every grid is declared ``dimension_semantics=("arbitrary", ...)``: the
+scratch statistics and the dW/dbias accumulators need steps in order.
+The gather family accumulates dW rows through revisited, gather-indexed
+output blocks, which the TPU pipeline does not support;
+``ops.mach_fused_xent_csr`` raises on TPU rather than dispatch it.
 """
 
 from __future__ import annotations
@@ -124,10 +130,18 @@ _LANE = 128
 # accumulators all require grid steps to run in order — declare every
 # grid axis "arbitrary" (sequential) so Mosaic may not parallelize or
 # reorder them.
-_SEQUENTIAL3 = pltpu.TPUCompilerParams(
+_SEQUENTIAL3 = pltpu.CompilerParams(
     dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
+_SEQUENTIAL2 = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary"))
 
 DEFAULT_VMEM_BUDGET = 6 * 2**20
+
+# f32 contractions at f32 precision: the TPU's default rounds matmul
+# operands to bf16, which the loss and its gradients must not inherit.
+_F32 = jax.lax.Precision.HIGHEST
+_dot = functools.partial(jnp.dot, precision=_F32,
+                         preferred_element_type=jnp.float32)
 
 
 def _align_columns(bc_cap: int, r: int, b: int) -> tuple[int, int, int]:
@@ -140,22 +154,25 @@ def _align_columns(bc_cap: int, r: int, b: int) -> tuple[int, int, int]:
     return bc_cap, r, round_up(b, bc_cap)
 
 
-def dense_tile_bytes(bn: int, bc: int, bd: int, rp: int) -> int:
+def dense_tile_bytes(bn: int, bc: int, bd: int, rp: int,
+                     rows: int = 0) -> int:
     """Accounted VMEM bytes of the dense kernels' resident tiles (f32),
     the max over the forward and backward pass:
 
     fwd:  h (bn,bd) + W (bd,bc) + bias (1,bc) + y (bn,rp) + loss (bn,1)
           + lse (bn,rp) + acc scratch (bn,bc) + 3 stats (bn,rp)
     bwd:  h + W + bias + y + lse + g (bn,1) + dh (bn,bd) + dW (bd,bc)
-          + dbias (1,bc) + acc/dlog scratch 2·(bn,bc)
+          + dbias (1,bc) + acc scratch (bn,bc) + the dlogits scratch of
+          every row of one backward call (rows,bc)
     """
     fwd = bn * bd + bd * bc + bc + 5 * bn * rp + bn + bn * bc
     bwd = 2 * bn * bd + 2 * bd * bc + 2 * bc + 2 * bn * rp + 2 * bn \
-        + 2 * bn * bc
+        + bn * bc + max(rows, bn) * bc
     return 4 * max(fwd, bwd)
 
 
-def sparse_tile_bytes(bn: int, bc: int, bd: int, rp: int, jp: int) -> int:
+def sparse_tile_bytes(bn: int, bc: int, bd: int, rp: int, jp: int,
+                      rows: int = 0) -> int:
     """Accounted VMEM bytes of the sparse kernels' resident tiles (f32).
     The per-step densify holds ~two (bn, jp, bd) one-hot intermediates
     on top of the ELL tiles; otherwise as ``dense_tile_bytes`` minus the
@@ -163,8 +180,18 @@ def sparse_tile_bytes(bn: int, bc: int, bd: int, rp: int, jp: int) -> int:
     densify = 2 * bn * jp * bd + 2 * bn * jp
     fwd = densify + bd * bc + bc + 5 * bn * rp + bn + bn * bc
     bwd = densify + 2 * bd * bc + 2 * bc + 2 * bn * rp + 2 * bn \
-        + 2 * bn * bc
+        + bn * bc + max(rows, bn) * bc
     return 4 * max(fwd, bwd)
+
+
+# Rows per backward call: every row's dlogits tile of one column block
+# stays in VMEM scratch between the two backward phases, so larger
+# batches run the backward kernel once per chunk of this many rows.
+_BWD_ROWS = 512
+
+
+def _bwd_rows(n: int, bn: int) -> int:
+    return min(round_up(n, bn), max(bn, _BWD_ROWS // bn * bn))
 
 
 def _candidates(override: Optional[int], pool: tuple[int, ...], pref: int,
@@ -207,7 +234,8 @@ def choose_fused_blocks(n: int, d: int, r: int, b: int,
         for bc_cap in bc_caps:
             bc, rp, bp = _align_columns(bc_cap, r, b)
             for bd in bd_cands:
-                if dense_tile_bytes(bn, bc, bd, rp) <= vmem_budget:
+                if dense_tile_bytes(bn, bc, bd, rp,
+                                    _bwd_rows(n, bn)) <= vmem_budget:
                     return bn, bc, bd, rp, bp
     bc_min, rp_min, _ = _align_columns(bc_caps[-1], r, b)
     raise ValueError(
@@ -248,7 +276,8 @@ def choose_sparse_blocks(n: int, d: int, r: int, b: int, j: int,
         bc, rp, bp = _align_columns(bc_cap, r, b)
         for bd in bd_cands:
             for bn in bn_cands:
-                if sparse_tile_bytes(bn, bc, bd, rp, jp) <= vmem_budget:
+                if sparse_tile_bytes(bn, bc, bd, rp, jp,
+                                     _bwd_rows(n, bn)) <= vmem_budget:
                     return bn, bc, bd, rp, bp, jp
     raise ValueError(
         f"no sparse fused-xent tiling fits vmem_budget={vmem_budget} "
@@ -387,20 +416,46 @@ def _tile_geometry(bc, bp, kblk):
     return nh, width, h0, boff
 
 
-def _mask_tile3(tile, bn, nh, width, boff, b):
-    """(bn, nh·width) f32 logits tile -> ((bn, nh, width) with padded
-    buckets at NEG_INF, per-position bucket ids)."""
-    tile3 = tile.reshape(bn, nh, width)
-    bidx = boff + jax.lax.broadcasted_iota(jnp.int32, (bn, nh, width), 2)
-    return jnp.where(bidx < b, tile3, NEG_INF), bidx
+def _column_block(acc, bias_ref, b, bc, bp, jblk):
+    """Finished logits of column block ``jblk`` -> (masked tile, bucket
+    ids, per-head lane masks, first head id)."""
+    nh, width, h0, boff = _tile_geometry(bc, bp, jblk)
+    tile, bidx = _finalize_tile(acc, bias_ref, b, width, boff)
+    return tile, bidx, _head_lanes(tile.shape, nh, width), h0
 
 
-def _finalize_tile(acc, bias_ref, bn, nh, width, boff, b):
-    """d-accumulated logits tile + broadcast bias row -> masked (bn,
-    nh, width) tile and bucket ids (the bias lands once, at the last d
-    block, where this is called)."""
+def _finalize_tile(acc, bias_ref, b, width, boff):
+    """d-accumulated (bn, bc) logits tile + broadcast bias row -> (tile
+    with padded buckets at NEG_INF, per-lane bucket ids).  The bias lands
+    once, at the last d block, where this is called."""
     tile = acc + bias_ref[...].astype(jnp.float32)      # (bn,bc)+(1,bc)
-    return _mask_tile3(tile, bn, nh, width, boff, b)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    bidx = boff + lane % width
+    return jnp.where(bidx < b, tile, NEG_INF), bidx
+
+
+def _head_lanes(shape, nh, width):
+    """Static lane mask of each of the nh heads in a (bn, nh·width) tile
+    (None when one head spans the whole tile).  The tile is never
+    reshaped to (bn, nh, width): that splits lanes when width < 128,
+    which Mosaic refuses."""
+    if nh == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return [(lane >= t * width) & (lane < (t + 1) * width)
+            for t in range(nh)]
+
+
+def _masked(mask, x, fill):
+    return x if mask is None else jnp.where(mask, x, fill)
+
+
+def _head_col(x, sel):
+    """Column of a (bn, rp) per-head array at the one lane ``sel`` marks
+    -> (bn, 1).  A masked lane sum: the head index is traced, and Mosaic
+    cannot slice lanes at a traced offset."""
+    return jnp.sum(jnp.where(sel, x, jnp.zeros_like(x)), axis=1,
+                   keepdims=True)
 
 
 def _densify_tile(cols_ref, vals_ref, d0, bn, jp, bd):
@@ -417,20 +472,34 @@ def _densify_tile(cols_ref, vals_ref, d0, bn, jp, bd):
     return jnp.sum(weighted, axis=1)                            # (bn, bd)
 
 
-def _online_update(tile3, bidx, y_ref, m_scr, s_scr, p_scr, h0, nh):
-    """Online per-head (max, sumexp, picked) accumulation on the nh
-    heads this column block touches."""
-    y_blk = y_ref[:, pl.ds(h0, nh)]                           # (bn, nh)
-    onehot = (bidx == y_blk[:, :, None]).astype(jnp.float32)
-    picked = jnp.sum(tile3 * onehot, axis=2)                  # (bn, nh)
-    m_old = m_scr[:, pl.ds(h0, nh)]
-    s_old = s_scr[:, pl.ds(h0, nh)]
-    m_new = jnp.maximum(m_old, jnp.max(tile3, axis=2))
-    s_new = s_old * jnp.exp(m_old - m_new) \
-        + jnp.sum(jnp.exp(tile3 - m_new[:, :, None]), axis=2)
-    m_scr[:, pl.ds(h0, nh)] = m_new
-    s_scr[:, pl.ds(h0, nh)] = s_new
-    p_scr[:, pl.ds(h0, nh)] = p_scr[:, pl.ds(h0, nh)] + picked
+def _online_update(tile, bidx, heads, y_ref, m_scr, s_scr, p_scr, h0):
+    """Online per-head (max, sumexp, picked) accumulation on the heads
+    this column block touches: ``heads[t]`` masks head h0 + t's lanes."""
+    m_all, s_all, p_all = m_scr[...], s_scr[...], p_scr[...]
+    y = y_ref[...]
+    lane_r = jax.lax.broadcasted_iota(jnp.int32, m_all.shape, 1)
+    m_lane = jnp.zeros_like(tile)
+    per_head = []
+    for t, mask in enumerate(heads):
+        sel = lane_r == h0 + t
+        m_old = _head_col(m_all, sel)
+        m_new = jnp.maximum(m_old, jnp.max(_masked(mask, tile, NEG_INF),
+                                           axis=1, keepdims=True))
+        m_lane = _masked(mask, m_new, m_lane)
+        per_head.append((sel, mask, m_old, m_new, _head_col(y, sel)))
+    e = jnp.exp(tile - m_lane)
+    for sel, mask, m_old, m_new, y_t in per_head:
+        s_t = jnp.sum(_masked(mask, e, 0.0), axis=1, keepdims=True)
+        hit = bidx == y_t
+        hit = hit if mask is None else hit & mask
+        p_t = jnp.sum(jnp.where(hit, tile, 0.0), axis=1, keepdims=True)
+        s_new = _head_col(s_all, sel) * jnp.exp(m_old - m_new) + s_t
+        s_all = jnp.where(sel, s_new, s_all)
+        m_all = jnp.where(sel, m_new, m_all)
+        p_all = jnp.where(sel, _head_col(p_all, sel) + p_t, p_all)
+    m_scr[...] = m_all
+    s_scr[...] = s_all
+    p_scr[...] = p_all
 
 
 def _flush_stats(r, loss_ref, lse_ref, m_scr, s_scr, p_scr):
@@ -443,20 +512,24 @@ def _flush_stats(r, loss_ref, lse_ref, m_scr, s_scr, p_scr):
     lse_ref[...] = jnp.where(head_ok, lse, 0.0)
 
 
-def _dlogits_from_tile(tile3, bidx, y_ref, lse_ref, g_ref, r, b, h0, nh,
-                       width):
-    """g·(softmax − onehot) from a masked logits tile, zeroed at padded
-    heads/buckets.  Returns (bn, nh·width) f32."""
-    bn = tile3.shape[0]
-    y_blk = y_ref[:, pl.ds(h0, nh)]
-    lse_blk = lse_ref[:, pl.ds(h0, nh)]                       # (bn, nh)
-    p = jnp.exp(tile3 - lse_blk[:, :, None])                  # softmax
-    onehot = (bidx == y_blk[:, :, None]).astype(jnp.float32)
-    head_ok = (h0 + jax.lax.broadcasted_iota(
-        jnp.int32, (bn, nh, width), 1)) < r
-    dtile3 = jnp.where((bidx < b) & head_ok,
-                       g_ref[...][:, :, None] * (p - onehot), 0.0)
-    return dtile3.reshape(bn, nh * width)
+def _dlogits_from_tile(tile, bidx, heads, y_ref, lse_ref, g_ref, r, b, h0):
+    """g·(softmax − onehot) from a masked (bn, bc) logits tile, zeroed at
+    padded heads/buckets."""
+    y, lse = y_ref[...], lse_ref[...]
+    lane_r = jax.lax.broadcasted_iota(jnp.int32, lse.shape, 1)
+    lse_lane = jnp.zeros_like(tile)
+    y_lane = jnp.zeros(tile.shape, jnp.int32)
+    ok_lane = jnp.zeros(tile.shape, jnp.bool_)
+    for t, mask in enumerate(heads):
+        sel = lane_r == h0 + t
+        lse_lane = _masked(mask, _head_col(lse, sel), lse_lane)
+        y_lane = _masked(mask, _head_col(y, sel), y_lane)
+        ok = h0 + t < r
+        ok_lane = (ok_lane | ok) if mask is None else (ok_lane
+                                                       | (mask & ok))
+    p = jnp.exp(tile - lse_lane)                              # softmax
+    onehot = (bidx == y_lane).astype(jnp.float32)
+    return jnp.where((bidx < b) & ok_lane, g_ref[...] * (p - onehot), 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -487,96 +560,98 @@ def _dblocked_fwd_step(a, bn, bc, r, rp, b, bp,
     def _init_acc():
         acc_scr[...] = jnp.zeros((bn, bc), jnp.float32)
 
-    acc_scr[...] += jnp.dot(a, w_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+    acc_scr[...] += _dot(a, w_ref[...].astype(jnp.float32))
 
     @pl.when(kd == nkd - 1)
     def _reduce():
-        nh, width, h0, boff = _tile_geometry(bc, bp, jblk)
-        tile3, bidx = _finalize_tile(acc_scr[...], bias_ref, bn, nh,
-                                     width, boff, b)
-        _online_update(tile3, bidx, y_ref, m_scr, s_scr, p_scr, h0, nh)
+        tile, bidx, heads, h0 = _column_block(acc_scr[...], bias_ref, b,
+                                              bc, bp, jblk)
+        _online_update(tile, bidx, heads, y_ref, m_scr, s_scr, p_scr, h0)
 
         @pl.when(jblk == njb - 1)
         def _flush():
             _flush_stats(r, loss_ref, lse_ref, m_scr, s_scr, p_scr)
 
 
-def _dblocked_bwd_step(a, nkd, bn, bc, r, rp, b, bp,
+def _bwd_cell(p, nrows, nkd):
+    """Backward grid step p -> (phase 2?, row block i, d block kd).
+
+    Phase 1 (p < nrows·nkd) sweeps the d blocks inside each row block to
+    rebuild its logits; phase 2 sweeps the row blocks inside each d
+    block, so every dW (and dh) output block is finished in consecutive
+    steps and written back once.  A TPU output block is never re-read
+    from HBM: an accumulator revisited after its index moved away would
+    start from stale VMEM."""
+    p1 = nrows * nkd
+    grad = p >= p1
+    q = jnp.where(grad, p - p1, p)
+    i = jnp.where(grad, q % nrows, q // nkd)
+    kd = jnp.where(grad, q // nrows, q % nkd)
+    return grad, i, kd
+
+
+def _dblocked_bwd_step(a, nrows, nkd, bn, bc, r, b, bp,
                        w_ref, bias_ref, y_ref, lse_ref, g_ref,
                        dw_ref, db_ref, acc_scr, dlog_scr, dh_ref=None):
-    """Single-recompute backward step;  grid (C/bc, N/bn, 2·D/bd).
-
-    ``a`` is the activation slice for d block ``k2 mod nkd`` (the
-    callers' index maps / densify offsets already fold the two-phase
-    k2 -> d-block mapping).  Phase 1 (k2 < nkd) rebuilds the logits
-    tile once, then at its last step forms dlogits into scratch and
-    reduces dbias into the revisited (1, bc) output row; phase 2
-    scatter-adds dW_blk += aᵀ @ dlogits through the revisited (bd, bc)
-    output window (initialized at the first row block,
-    read-modify-written on later revisits — phase-1 steps map the same
-    block but leave it untouched) and, when ``dh_ref`` is given (dense
-    h), dh_blk += dlogits @ Wᵀ through the revisited (bn, bd) output
-    block (initialized at the first column block)."""
+    """Single-recompute backward step;  grid (C/bc, 2·nrows·nkd), see
+    ``_bwd_cell``.  ``a`` is the activation slice of the step's (row
+    block, d block).  Phase 1 rebuilds each row block's logits tile
+    once, then at its last d block writes dlogits into the (rows, bc)
+    scratch and reduces dbias into the (1, bc) output row.  Phase 2
+    accumulates dW_blk += aᵀ @ dlogits over the row blocks of one d
+    block and, when ``dh_ref`` is given (dense h), writes this column
+    block's dh_blk = dlogits @ Wᵀ (the caller sums the column blocks)."""
     jblk = pl.program_id(0)
-    iblk = pl.program_id(1)
-    k2 = pl.program_id(2)
+    grad, i, kd = _bwd_cell(pl.program_id(1), nrows, nkd)
+    rows = pl.ds(pl.multiple_of(i * bn, 8), bn)
 
-    @pl.when(k2 < nkd)
+    @pl.when(jnp.logical_not(grad))
     def _logits_phase():
-        @pl.when(k2 == 0)
+        @pl.when(kd == 0)
         def _init():
             acc_scr[...] = jnp.zeros((bn, bc), jnp.float32)
 
-        acc_scr[...] += jnp.dot(a, w_ref[...].astype(jnp.float32),
-                                preferred_element_type=jnp.float32)
+        acc_scr[...] += _dot(a, w_ref[...].astype(jnp.float32))
 
-        @pl.when(k2 == nkd - 1)
+        @pl.when(kd == nkd - 1)
         def _dlog():
-            nh, width, h0, boff = _tile_geometry(bc, bp, jblk)
-            tile3, bidx = _finalize_tile(acc_scr[...], bias_ref, bn, nh,
-                                         width, boff, b)
-            dlog_scr[...] = _dlogits_from_tile(
-                tile3, bidx, y_ref, lse_ref, g_ref, r, b, h0, nh, width)
-            db_contrib = jnp.sum(dlog_scr[...], axis=0, keepdims=True)
+            tile, bidx, heads, h0 = _column_block(acc_scr[...], bias_ref,
+                                                  b, bc, bp, jblk)
+            dlog = _dlogits_from_tile(tile, bidx, heads, y_ref, lse_ref,
+                                      g_ref, r, b, h0)
+            dlog_scr[rows, :] = dlog
+            db_contrib = jnp.sum(dlog, axis=0, keepdims=True)
 
-            @pl.when(iblk == 0)
+            @pl.when(i == 0)
             def _db_first():
                 db_ref[...] = db_contrib
 
-            @pl.when(iblk > 0)
+            @pl.when(i > 0)
             def _db_acc():
                 db_ref[...] += db_contrib
 
-    @pl.when(k2 >= nkd)
+    @pl.when(grad)
     def _grad_phase():
-        dlog = dlog_scr[...]
+        dlog = dlog_scr[rows, :]
         dw_contrib = jax.lax.dot_general(
             a, dlog,
             dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bd, bc)
+            precision=_F32, preferred_element_type=jnp.float32)  # (bd, bc)
 
-        @pl.when(iblk == 0)
+        @pl.when(i == 0)
         def _dw_first():
             dw_ref[...] = dw_contrib
 
-        @pl.when(iblk > 0)
+        @pl.when(i > 0)
         def _dw_acc():
             dw_ref[...] += dw_contrib
 
         if dh_ref is not None:
-            dh_contrib = jax.lax.dot_general(
+            dh_ref[0] = jax.lax.dot_general(
                 dlog, w_ref[...].astype(jnp.float32),
                 dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=_F32,
                 preferred_element_type=jnp.float32)           # (bn, bd)
-
-            @pl.when(jblk == 0)
-            def _dh_first():
-                dh_ref[...] = dh_contrib
-
-            @pl.when(jblk > 0)
-            def _dh_acc():
-                dh_ref[...] += dh_contrib
 
 
 # ---------------------------------------------------------------------------
@@ -593,13 +668,13 @@ def _fwd_body(bn, bc, r, rp, b, bp,
                        acc_scr, m_scr, s_scr, p_scr)
 
 
-def _bwd_body(bn, bc, nkd, r, rp, b, bp,
+def _bwd_body(bn, bc, nrows, nkd, r, b, bp,
               h_ref, w_ref, bias_ref, y_ref, lse_ref, g_ref,
               dh_ref, dw_ref, db_ref, acc_scr, dlog_scr):
-    """The h/W/dh/dW index maps fold k2 -> k2 mod nkd, so ``h_ref`` is
-    the right (bn, bd) slice in both phases."""
-    _dblocked_bwd_step(h_ref[...].astype(jnp.float32), nkd, bn, bc, r,
-                       rp, b, bp, w_ref, bias_ref, y_ref, lse_ref, g_ref,
+    """The h/W index maps follow ``_bwd_cell``, so ``h_ref`` is the
+    step's (bn, bd) slice in both phases."""
+    _dblocked_bwd_step(h_ref[...].astype(jnp.float32), nrows, nkd, bn, bc,
+                       r, b, bp, w_ref, bias_ref, y_ref, lse_ref, g_ref,
                        dw_ref, db_ref, acc_scr, dlog_scr, dh_ref=dh_ref)
 
 
@@ -616,15 +691,13 @@ def _sparse_fwd_body(bn, bc, bd, r, rp, b, bp, jp,
                        loss_ref, lse_ref, acc_scr, m_scr, s_scr, p_scr)
 
 
-def _sparse_bwd_body(bn, bc, bd, nkd, r, rp, b, bp, jp,
+def _sparse_bwd_body(bn, bc, bd, nrows, nkd, r, b, bp, jp,
                      cols_ref, vals_ref, w_ref, bias_ref, y_ref, lse_ref,
                      g_ref, dw_ref, db_ref, acc_scr, dlog_scr):
-    """No dh: ``vals`` is data (zero cotangent).  The densify offset
-    folds the two-phase k2 -> d-block mapping itself."""
-    k2 = pl.program_id(2)
-    kd = jnp.where(k2 >= nkd, k2 - nkd, k2)
+    """No dh: ``vals`` is data (zero cotangent)."""
+    _, _, kd = _bwd_cell(pl.program_id(1), nrows, nkd)
     a = _densify_tile(cols_ref, vals_ref, kd * bd, bn, jp, bd)
-    _dblocked_bwd_step(a, nkd, bn, bc, r, rp, b, bp, w_ref, bias_ref,
+    _dblocked_bwd_step(a, nrows, nkd, bn, bc, r, b, bp, w_ref, bias_ref,
                        y_ref, lse_ref, g_ref, dw_ref, db_ref, acc_scr,
                        dlog_scr)
 
@@ -663,10 +736,9 @@ def _gather_fwd_body(r, rp, b, bp, bc,
 
     @pl.when(jj == nj - 1)
     def _reduce():
-        nh, width, h0, boff = _tile_geometry(bc, bp, jblk)
-        tile3, bidx = _finalize_tile(acc_scr[...], bias_ref, 1, nh,
-                                     width, boff, b)
-        _online_update(tile3, bidx, y_ref, m_scr, s_scr, p_scr, h0, nh)
+        tile, bidx, heads, h0 = _column_block(acc_scr[...], bias_ref, b,
+                                              bc, bp, jblk)
+        _online_update(tile, bidx, heads, y_ref, m_scr, s_scr, p_scr, h0)
 
         @pl.when(jblk == njb - 1)
         def _flush():
@@ -704,11 +776,10 @@ def _gather_bwd_body(r, rp, b, bp, bc,
 
         @pl.when(k2 == nj - 1)
         def _dlog():
-            nh, width, h0, boff = _tile_geometry(bc, bp, jblk)
-            tile3, bidx = _finalize_tile(acc_scr[...], bias_ref, 1, nh,
-                                         width, boff, b)
+            tile, bidx, heads, h0 = _column_block(acc_scr[...], bias_ref,
+                                                  b, bc, bp, jblk)
             dlog_scr[...] = _dlogits_from_tile(
-                tile3, bidx, y_ref, lse_ref, g_ref, r, b, h0, nh, width)
+                tile, bidx, heads, y_ref, lse_ref, g_ref, r, b, h0)
             db_ref[...] += dlog_scr[...]
 
     @pl.when(k2 >= nj)
@@ -763,27 +834,44 @@ def _fused_call(kind, h2p, wp, biasp, yp, lsep, gp, dims, bn, bc, bd,
             compiler_params=_SEQUENTIAL3,
             interpret=interpret,
         )(h2p, wp, biasp, yp)
-    # bwd: column blocks outer, 2·D/bd minor; both phases of a (j, i)
-    # cell map the same h/W/dh/dW d-block
-    kmap = lambda k2: jnp.where(k2 >= nkd, k2 - nkd, k2)
-    h_spec = pl.BlockSpec((bn, bd), lambda j, i, k2: (i, kmap(k2)))
-    w_spec = pl.BlockSpec((bd, bc), lambda j, i, k2: (kmap(k2), j))
-    b_spec = pl.BlockSpec((1, bc), lambda j, i, k2: (0, j))
+    # bwd: column blocks outer, then the two phases of ``_bwd_cell``
+    nrows = npad // bn
+
+    def cell(p):
+        return _bwd_cell(p, nrows, nkd)
+
+    def dw_index(j, p):
+        grad, _, kd = cell(p)
+        return jnp.where(grad, kd, 0), j
+
+    def dh_index(j, p):
+        grad, i, kd = cell(p)
+        return j, jnp.where(grad, i, 0), jnp.where(grad, kd, 0)
+
     row_spec = lambda width: pl.BlockSpec((bn, width),
-                                          lambda j, i, k2: (i, 0))
+                                          lambda j, p: (cell(p)[1], 0))
     return pl.pallas_call(
-        functools.partial(_bwd_body, bn, bc, nkd, r, rp, b, bp),
-        grid=(c // bc, npad // bn, 2 * nkd),
-        in_specs=[h_spec, w_spec, b_spec, row_spec(rp), row_spec(rp),
-                  row_spec(1)],
-        out_specs=(h_spec, w_spec, b_spec),
-        out_shape=(jax.ShapeDtypeStruct((npad, dp), jnp.float32),
+        functools.partial(_bwd_body, bn, bc, nrows, nkd, r, b, bp),
+        grid=(c // bc, 2 * nrows * nkd),
+        in_specs=[pl.BlockSpec((bn, bd), lambda j, p: cell(p)[1:]),
+                  pl.BlockSpec((bd, bc), lambda j, p: (cell(p)[2], j)),
+                  pl.BlockSpec((1, bc), lambda j, p: (0, j)),
+                  row_spec(rp), row_spec(rp), row_spec(1)],
+        out_specs=(pl.BlockSpec((1, bn, bd), dh_index),
+                   pl.BlockSpec((bd, bc), dw_index),
+                   pl.BlockSpec((1, bc), lambda j, p: (0, j))),
+        out_shape=(jax.ShapeDtypeStruct((c // bc, npad, dp), jnp.float32),
                    jax.ShapeDtypeStruct((dp, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((bn, bc), jnp.float32)] * 2,
-        compiler_params=_SEQUENTIAL3,
+        scratch_shapes=[pltpu.VMEM((bn, bc), jnp.float32),
+                        pltpu.VMEM((npad, bc), jnp.float32)],
+        compiler_params=_SEQUENTIAL2,
         interpret=interpret,
     )(h2p, wp, biasp, yp, lsep, gp)
+
+
+def _row_chunks(npad: int, rows: int) -> list[slice]:
+    return [slice(s, min(s + rows, npad)) for s in range(0, npad, rows)]
 
 
 def _check_shapes(h2, w, bias, hashed_labels, num_buckets):
@@ -826,8 +914,13 @@ def _fused_bwd(num_buckets, block_n, block_c, block_d, interpret, res, g):
     gp = jnp.pad(g.astype(jnp.float32).reshape(n, 1),
                  ((0, npad - n), (0, 0)))
     lsep = jnp.pad(lse, ((0, npad - n), (0, 0)))
-    dhp, dwp, dbp = _fused_call("bwd", h2p, wp, biasp, yp, lsep, gp,
-                                dims, bn, bc, bd, interpret)
+    parts = [_fused_call("bwd", h2p[sl], wp, biasp, yp[sl], lsep[sl],
+                         gp[sl], (sl.stop - sl.start,) + dims[1:], bn, bc,
+                         bd, interpret)
+             for sl in _row_chunks(npad, _bwd_rows(n, bn))]
+    dhp = jnp.concatenate([dh.sum(axis=0) for dh, _, _ in parts])
+    dwp = sum(dw for _, dw, _ in parts)
+    dbp = sum(db for _, _, db in parts)
     dh = dhp[:n, :d].astype(h2.dtype)
     dw = dwp.reshape(dp, rp, bp)[:d, :r, :b].reshape(d, r * b) \
         .astype(w.dtype)
@@ -893,25 +986,34 @@ def _sparse_call(kind, colsp, valsp, wp, biasp, yp, lsep, gp, dims, bn,
             compiler_params=_SEQUENTIAL3,
             interpret=interpret,
         )(colsp, valsp, wp, biasp, yp)
-    # bwd: both phases of a (j, i) cell map the same dW/W d-block
-    kmap = lambda k2: jnp.where(k2 >= nkd, k2 - nkd, k2)
-    dw_spec = pl.BlockSpec((bd, bc), lambda j, i, k2: (kmap(k2), j))
-    b_spec = pl.BlockSpec((1, bc), lambda j, i, k2: (0, j))
-    ell_spec = pl.BlockSpec((bn, jp), lambda j, i, k2: (i, 0))
+    # bwd: column blocks outer, then the two phases of ``_bwd_cell``
+    nrows = npad // bn
+
+    def cell(p):
+        return _bwd_cell(p, nrows, nkd)
+
+    def dw_index(j, p):
+        grad, _, kd = cell(p)
+        return jnp.where(grad, kd, 0), j
+
+    ell_spec = pl.BlockSpec((bn, jp), lambda j, p: (cell(p)[1], 0))
     row_spec = lambda width: pl.BlockSpec((bn, width),
-                                          lambda j, i, k2: (i, 0))
+                                          lambda j, p: (cell(p)[1], 0))
     return pl.pallas_call(
-        functools.partial(_sparse_bwd_body, bn, bc, bd, nkd, r, rp, b,
+        functools.partial(_sparse_bwd_body, bn, bc, bd, nrows, nkd, r, b,
                           bp, jp),
-        grid=(c // bc, npad // bn, 2 * nkd),
-        in_specs=[ell_spec, ell_spec, dw_spec, b_spec, row_spec(rp),
-                  row_spec(rp), row_spec(1)],
-        out_specs=(dw_spec, b_spec),
+        grid=(c // bc, 2 * nrows * nkd),
+        in_specs=[ell_spec, ell_spec,
+                  pl.BlockSpec((bd, bc), lambda j, p: (cell(p)[2], j)),
+                  pl.BlockSpec((1, bc), lambda j, p: (0, j)),
+                  row_spec(rp), row_spec(rp), row_spec(1)],
+        out_specs=(pl.BlockSpec((bd, bc), dw_index),
+                   pl.BlockSpec((1, bc), lambda j, p: (0, j))),
         out_shape=(jax.ShapeDtypeStruct((dp, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((bn, bc), jnp.float32),
-                        pltpu.VMEM((bn, bc), jnp.float32)],
-        compiler_params=_SEQUENTIAL3,
+                        pltpu.VMEM((npad, bc), jnp.float32)],
+        compiler_params=_SEQUENTIAL2,
         interpret=interpret,
     )(colsp, valsp, wp, biasp, yp, lsep, gp)
 
@@ -961,8 +1063,12 @@ def _sparse_bwd(num_buckets, block_n, block_c, block_d, interpret, res, g):
     gp = jnp.pad(g.astype(jnp.float32).reshape(n, 1),
                  ((0, npad - n), (0, 0)))
     lsep = jnp.pad(lse, ((0, npad - n), (0, 0)))
-    dwp, dbp = _sparse_call("bwd", colsp, valsp, wp, biasp, yp, lsep, gp,
-                            dims, bn, bc, bd, jp, interpret)
+    parts = [_sparse_call("bwd", colsp[sl], valsp[sl], wp, biasp, yp[sl],
+                          lsep[sl], gp[sl], (sl.stop - sl.start,) + dims[1:],
+                          bn, bc, bd, jp, interpret)
+             for sl in _row_chunks(npad, _bwd_rows(n, bn))]
+    dwp = sum(dw for dw, _ in parts)
+    dbp = sum(db for _, db in parts)
     dw = dwp.reshape(dp, rp, bp)[:d, :r, :b].reshape(d, r * b) \
         .astype(w.dtype)
     # features are data: zero cotangent for vals, none for int cols/labels

@@ -1,36 +1,21 @@
 """Fused streaming top-k MACH decode (the sampling/serving hot path).
 
-``mach_decode.py`` fuses Algorithm 2's *argmax* — a running top-1 in
-VMEM scratch across K blocks.  Every path that needs more than the
-argmax (top-k sampling, the min/median estimators, retrieval-style
-serving) previously fell back to materializing the full (N, K) score
-matrix in HBM, re-introducing the O(N·K) traffic the fused kernel was
-built to avoid.  This kernel generalizes the running accumulator to a
-streaming *top-k*:
+Every path that needs more than the argmax — top-k sampling, the
+min/median estimators, retrieval-style serving — goes through the
+streaming kernel in ``mach_decode.py``: per K block the estimator's
+scores are built in VMEM from on-the-fly one-hot matmuls and merged into
+a running top-k held in VMEM scratch, so the (N, K) score matrix never
+exists anywhere.  This module is its public top-k entry: argument
+checks, and values on the estimator's scale —
 
-  * per K block, scores are built in VMEM with the same on-the-fly
-    multi-hot matmul recast (MXU, depth R·B) as the top-1 kernel;
-  * the block's ``jax.lax.top_k`` is merged into a running (values,
-    indices) top-k held in VMEM scratch via one stable two-operand
-    sort over the 2·kcap concatenation — the (bn, bk) score tile never
-    leaves VMEM and the (N, K) matrix never exists anywhere;
-  * the per-block reduction over the R axis is swappable, giving all
-    three paper estimators:
-        unbiased  (Eq. 2)  — single (bn, R·B) @ (R·B, bk) matmul (the
-                             affine map is applied after selection; it
-                             is monotone so the ordering is identical),
-        min       (Eq. 7)  — R batched one-hot matmuls (exact gathers),
-                             then min over R,
-        median    (Eq. 8)  — same, then median over R.
+    unbiased  (Eq. 2)  — the affine map of the summed scores, applied
+                         after selection (it is monotone, so the order
+                         is identical),
+    min       (Eq. 7)  — the min over the R gathered values,
+    median    (Eq. 8)  — their median.
 
-Tie-breaking matches ``jax.lax.top_k`` on the full score matrix: the
-running set (earlier K blocks → lower class ids) is concatenated first
-and the merge sort is stable, so equal values resolve to the lowest
-class id.
-
-Hash sources mirror the top-1 kernel: a tiled (R, K) bucket table
-(any 2-universal family) or inline multiply-shift hashing computed
-in-register (B = 2^k), which removes the table from HBM entirely.
+Ties resolve to the lowest class id, like ``jax.lax.top_k`` on the full
+score matrix.
 
 HBM traffic: O(N·R·B + K·R [table mode] + N·k) vs the reference path's
 O(N·K) score materialization — the paper's O(RBd + KR) serving claim,
@@ -39,94 +24,12 @@ extended from argmax to top-k.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.estimators import ESTIMATORS
-from repro.kernels.mach_decode import (NEG_INF, choose_decode_blocks,
-                                       mask_k_tail, multihot_block,
-                                       prepare_decode_operands, round_up)
-
-_LANE = 128          # TPU lane width: running-top-k capacity granularity
-
-
-def _merge_topk(run_val, run_idx, blk_val, blk_idx, kcap):
-    """Stable descending merge of two (bn, kcap) top-k sets.
-
-    One two-operand sort over the concatenation; stability + run-first
-    ordering reproduces lax.top_k's lowest-index tie-breaking globally.
-    """
-    cat_val = jnp.concatenate([run_val, blk_val], axis=-1)   # (bn, 2·kcap)
-    cat_idx = jnp.concatenate([run_idx, blk_idx], axis=-1)
-    neg_val, idx = jax.lax.sort((-cat_val, cat_idx), dimension=-1,
-                                is_stable=True, num_keys=1)
-    return -neg_val[:, :kcap], idx[:, :kcap]
-
-
-def _block_scores(probs, m, bn, r, b, bk, estimator):
-    """Per-block estimator scores (bn, bk) from the VMEM multi-hot m.
-
-    probs: (bn, R·B) f32;  m: (R, B, bk) f32 one-hot over buckets.
-    """
-    if estimator == "unbiased":
-        # sum over R in the contraction itself — one MXU matmul of
-        # depth R·B; the affine map of Eq. 2 is applied post-selection.
-        return jnp.dot(probs, m.reshape(r * b, bk),
-                       preferred_element_type=jnp.float32)
-    # min/median need the per-repetition gathered values: R batched
-    # one-hot matmuls (exact gathers on the MXU — each row of m has at
-    # most one 1), then the order statistic over R.
-    g = jax.lax.dot_general(
-        probs.reshape(bn, r, b), m,
-        dimension_numbers=(((2,), (1,)), ((1,), (0,))),
-        preferred_element_type=jnp.float32)                  # (R, bn, bk)
-    if estimator == "min":
-        return jnp.min(g, axis=0)
-    return jnp.median(g, axis=0)
-
-
-def _topk_body(num_classes, bn, bk, r, b, kcap, estimator, inline_shift,
-               probs_ref, hash_ref, val_out, idx_out, run_val, run_idx):
-    """Grid (N/bn, K/bk), K minor.  hash_ref is the (r, bk) table tile in
-    table mode or the (r, 1) uint32 coefficients in inline mode."""
-    kblk = pl.program_id(1)
-    nk = pl.num_programs(1)
-    kbase = kblk * bk
-
-    @pl.when(kblk == 0)
-    def _init():
-        run_val[...] = jnp.full((bn, kcap), NEG_INF, jnp.float32)
-        run_idx[...] = jnp.zeros((bn, kcap), jnp.int32)
-
-    m = multihot_block(hash_ref, inline_shift, kbase, r, b, bk)
-    scores = _block_scores(probs_ref[...].astype(jnp.float32),
-                           m, bn, r, b, bk, estimator)        # (bn, bk)
-    scores = mask_k_tail(scores, kbase, num_classes, bn, bk)
-
-    blk_val, blk_pos = jax.lax.top_k(scores, kcap)
-    blk_idx = kbase + blk_pos.astype(jnp.int32)
-
-    # Merge only when some block entry can displace a kept one.  Ties
-    # resolve to the running set (lower class ids, stable run-first
-    # merge), so skipping on <= is exact — and most K blocks of a
-    # selective decode never beat the running floor, making the skip the
-    # common case.
-    @pl.when(jnp.max(blk_val) > jnp.min(run_val[...]))
-    def _merge():
-        new_val, new_idx = _merge_topk(run_val[...], run_idx[...],
-                                       blk_val, blk_idx, kcap)
-        run_val[...] = new_val
-        run_idx[...] = new_idx
-
-    @pl.when(kblk == nk - 1)
-    def _flush():
-        val_out[...] = run_val[...]
-        idx_out[...] = run_idx[...]
+from repro.kernels.mach_decode import streaming_topk
 
 
 def mach_topk_pallas(meta_probs: jnp.ndarray,
@@ -148,41 +51,18 @@ def mach_topk_pallas(meta_probs: jnp.ndarray,
     order).  Exactly one of ``table`` ((R, K) int32) or
     (``inline_coeffs`` ((R,) uint32), ``inline_shift``) must be given.
     """
-    n, r, b = meta_probs.shape
+    _, r, b = meta_probs.shape
     if estimator not in ESTIMATORS:
         raise ValueError(f"estimator must be one of {ESTIMATORS}, "
                          f"got {estimator!r}")
     if not 1 <= k <= num_classes:
         raise ValueError(f"need 1 <= k <= num_classes, got k={k}, "
                          f"num_classes={num_classes}")
-    rb = r * b
-    kcap = round_up(k, _LANE)            # lane-aligned running capacity
-    # estimator-aware tile accounting: min/median also hold the
-    # (R, bn, bk) gathered tensor in VMEM alongside the (R·B, bk)
-    # multi-hot, and the merge scratch scales with kcap.
-    bn, bk = choose_decode_blocks(n, rb, block_n, block_k,
-                                  r=r, estimator=estimator, kcap=kcap)
-    bk = max(round_up(bk, _LANE), kcap)  # block top_k needs bk >= kcap
-    k_grid = pl.cdiv(num_classes, bk)
-    probs2d, npad, hash_arg, hash_spec, shift = prepare_decode_operands(
-        meta_probs, table, num_classes, inline_coeffs, inline_shift, bn, bk,
-        k_grid)
-
-    val, idx = pl.pallas_call(
-        functools.partial(_topk_body, num_classes, bn, bk, r, b, kcap,
-                          estimator, shift),
-        grid=(npad // bn, k_grid),
-        in_specs=[pl.BlockSpec((bn, rb), lambda i, j: (i, 0)), hash_spec],
-        out_specs=(pl.BlockSpec((bn, kcap), lambda i, j: (i, 0)),
-                   pl.BlockSpec((bn, kcap), lambda i, j: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((npad, kcap), jnp.float32),
-                   jax.ShapeDtypeStruct((npad, kcap), jnp.int32)),
-        scratch_shapes=[pltpu.VMEM((bn, kcap), jnp.float32),
-                        pltpu.VMEM((bn, kcap), jnp.int32)],
-        interpret=interpret,
-    )(probs2d, hash_arg)
-
-    val, idx = val[:n, :k], idx[:n, :k]
+    val, idx = streaming_topk(
+        meta_probs, table, num_classes=num_classes, k=k,
+        estimator=estimator, inline_coeffs=inline_coeffs,
+        inline_shift=inline_shift, block_n=block_n, block_k=block_k,
+        interpret=interpret)
     if estimator == "unbiased":
         # Eq. 2's affine map of the summed scores — monotone, so applying
         # it after selection preserves the ordering bit-for-bit.

@@ -1,10 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Dispatch policy: on TPU the Pallas kernels run natively; elsewhere
-(this CPU container) they run with ``interpret=True`` when
-``use_pallas=True`` is forced (tests) and otherwise fall back to the
-pure-jnp reference, which is semantically identical.  Call sites never
-branch on platform themselves.
+Dispatch policy: on TPU the Pallas kernels run natively
+(``tests/test_tpu_compile.py`` compiles each one for a described v5e).
+Elsewhere they run in interpret mode only when a caller forces
+``use_pallas=True`` (tests and CPU gates), and otherwise fall back to
+the pure-jnp reference, which is semantically identical.  A kernel that
+does not run on TPU raises there, naming itself — never a silent jnp
+route.  Call sites never branch on platform themselves.
 """
 
 from __future__ import annotations
@@ -37,6 +39,12 @@ CANDIDATE_EXACT = "exact"
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _not_on_tpu(kernel: str, why: str) -> None:
+    """Raise for a kernel that the TPU dispatch cannot run natively."""
+    raise NotImplementedError(
+        f"{kernel} does not run on TPU: {why} (ROADMAP Queue A)")
 
 
 def _table_from_inline(inline_coeffs: jnp.ndarray, inline_shift: int,
@@ -235,14 +243,19 @@ def mach_topk_candidates(meta_probs: jnp.ndarray,
     R·m·L·R) — independent of K.
 
     The fused Pallas pipeline needs inline multiply-shift hashing (it
-    recomputes buckets in-register); in table mode the pure-jnp path
-    runs regardless of ``use_pallas``.
+    recomputes buckets in-register).  Table mode runs the pure-jnp path
+    off TPU; on TPU it raises rather than leave the chip for jnp.
     """
     lead = meta_probs.shape[:-2]
     r, b = meta_probs.shape[-2:]
     flat = meta_probs.reshape((-1, r, b))
     use = _on_tpu() if use_pallas is None else use_pallas
-    if use and inline_coeffs is not None and inline_shift is not None:
+    inline = inline_coeffs is not None and inline_shift is not None
+    if use and not inline and _on_tpu():
+        _not_on_tpu("mach_candidate_topk_pallas (table mode)",
+                    "the fused filter hashes in-register and needs inline "
+                    "multiply-shift coefficients")
+    if use and inline:
         interp = (not _on_tpu()) if interpret is None else interpret
         val, idx = mach_candidate_topk_pallas(
             flat, inverted, num_classes=num_classes, k=k, m=m, t=t,
@@ -412,6 +425,12 @@ def mach_fused_xent_csr(indptr: jnp.ndarray, indices: jnp.ndarray,
             except ValueError:
                 impl = "gather"
     if impl == "gather":
+        if not interp:
+            _not_on_tpu(
+                "mach_fused_xent_gather_pallas",
+                "its (1, bc) W-row blocks break Mosaic's (8, 128) tiling, "
+                "and its dW rows accumulate through revisited output "
+                "blocks, which a TPU never re-reads from HBM")
         return mach_fused_xent_gather_pallas(
             cols, vals, w, bias, hashed_labels.astype(jnp.int32),
             num_buckets, block_c, interp)

@@ -9,6 +9,14 @@ initialization and only then calls these.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """Auto axes: shardings are propagated by the compiler from the
+    parameters' placements and ``with_sharding_constraint`` — the model
+    code carries no explicit-sharding annotations."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,14 +24,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """Whatever this host has — used by tests/examples on CPU."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline analysis (per chip)

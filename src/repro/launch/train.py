@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCH_IDS, get_config
 from repro.data import LMDataConfig, SyntheticLMStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models.frontends import AUDIO_FEATURE_DIM, VISION_FEATURE_DIM
 from repro.models.model import LanguageModel
@@ -48,6 +49,7 @@ def main() -> int:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_pod_ckpt")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = LanguageModel(cfg)

@@ -76,6 +76,20 @@ def make_train_step(loss_fn: Callable[[Any, dict], tuple],
     return step_fn, opt
 
 
+def make_head_step(loss_fn: Callable, opt):
+    """The jitted step of a MACH head trained on its own (the paper's
+    logistic-regression runs): ``loss_fn(params, x, y)`` -> scalar;
+    returns ``step(params, opt_state, x, y) -> (params, opt_state,
+    loss)``.  params and opt_state are donated — updated in place."""
+
+    def step(params, opt_state, x, y):
+        loss, grads = jax.value_and_grad(loss_fn)(params, x, y)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return apply_updates(params, updates), opt_state, loss
+
+    return jax.jit(step, donate_argnums=(0, 1))
+
+
 class Trainer:
     """Single-host convenience driver (examples, tests).  The pod-scale
     path is launch/train.py which jits the same step under a mesh."""

@@ -65,11 +65,16 @@ def test_fused_xent_matches_ref(n, d, r, b, dtype):
         mach_fused_xent_pallas(h_, w_, None, y, b, None, None, None,
                                True) * g),
         argnums=(0, 1))(h, w)
+    # Both paths accumulate in f32 but in different orders, then cast
+    # the grad to the input dtype.  For bf16 an f32 value that lies near
+    # the midpoint of two bf16 neighbours can round either way: one bf16
+    # ulp, at most 2^-7·|x| (8 significand bits).  f32 keeps its bound.
+    rtol = 2.0**-7 if dtype == jnp.bfloat16 else 1e-4
     for a, k in zip(dr, dk):
         assert a.dtype == k.dtype
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(k, np.float32),
-                                   rtol=1e-4, atol=1e-5)
+                                   rtol=rtol, atol=1e-5)
 
 
 @pytest.mark.parametrize("n,d,r,b", [
@@ -102,6 +107,45 @@ def test_fused_xent_bias_matches_ref(n, d, r, b, dtype):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(k, np.float32),
                                    rtol=rtol, atol=atol)
+
+
+@pytest.mark.parametrize("sparse", [False, True])
+def test_fused_xent_backward_row_chunks(monkeypatch, sparse):
+    """A batch larger than one backward call's rows (its dlogits stay
+    in VMEM between the two backward phases) runs the backward kernel
+    per row chunk; the summed grads must equal the oracle's."""
+    import repro.kernels.mach_fused_xent as kmod
+    monkeypatch.setattr(kmod, "_BWD_ROWS", 8)
+    n, d, r, b, j = 21, 32, 3, 16, 5
+    h, w, bias, y, g = _case(n, d, r, b, with_bias=True)
+    if sparse:
+        k1, k2 = jax.random.split(jax.random.key(9))
+        cols = jax.random.randint(k1, (n, j), 0, d)
+        vals = jax.random.uniform(k2, (n, j))
+        indptr = jnp.arange(n + 1, dtype=jnp.int32) * j
+
+        def kern(w_, b_):
+            return kmod.mach_fused_xent_sparse_pallas(
+                cols, vals, w_, b_, y, b, None, None, None, True)
+
+        def oracle(w_, b_):
+            return ref.mach_fused_xent_csr_ref(
+                indptr, cols.reshape(-1), vals.reshape(-1), w_, y, b,
+                bias=b_)
+    else:
+        def kern(w_, b_):
+            return mach_fused_xent_pallas(h, w_, b_, y, b, None, None, None,
+                                          True)
+
+        def oracle(w_, b_):
+            return ref.mach_fused_xent_ref(h, w_, y, b, bias=b_)
+    dk = jax.grad(lambda w_, b_: jnp.sum(kern(w_, b_) * g),
+                  argnums=(0, 1))(w, bias)
+    dr = jax.grad(lambda w_, b_: jnp.sum(oracle(w_, b_) * g),
+                  argnums=(0, 1))(w, bias)
+    for a, k in zip(dr, dk):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(k),
+                                   rtol=1e-4, atol=1e-5)
 
 
 def test_fused_xent_head_split_blocks():

@@ -150,48 +150,52 @@ def test_choose_decode_blocks_rounds_bn():
     """bn is clamped to a multiple of 8 whatever the caller passes."""
     for block_n, want in [(1, 8), (5, 8), (8, 8), (13, 16), (100, 104),
                           (None, 8)]:
-        bn, bk = choose_decode_blocks(7, 64, block_n, None)
+        bn, bk, nh = choose_decode_blocks(7, 4, 16, block_n, None)
         if block_n is not None:
             assert bn == want
         assert bn % 8 == 0
-        assert bk % 128 == 0
+        assert bk % 128 == 0 and 1 <= nh <= 4
 
 
 def test_choose_decode_blocks_budget_sweep():
     """Explicit tile-byte accounting: over a sweep of VMEM budgets and
-    estimator / kcap configurations the chosen tile always fits, bk is
-    lane-aligned, min/median never pick a wider bk than unbiased at the
-    same budget (their per-repetition score cube is accounted), and the
-    floor tile overflowing raises instead of silently clamping."""
+    estimator / kcap configurations the chosen tile always fits and bk
+    is lane-aligned; median never picks a wider tile than unbiased at
+    the same budget (its per-repetition score cube is accounted); at
+    retrieval widths (R·B = 131,072) the repetitions are tiled instead
+    of overflowing; the floor tile overflowing raises instead of
+    silently clamping."""
     from repro.kernels.mach_decode import decode_tile_bytes
-    rb, r, n = 8 * 128, 8, 8
+    r, b, n = 8, 128, 8
     for budget in (4 * 2**20, 6 * 2**20, 16 * 2**20):
-        bks = {}
+        tiles = {}
         for est in ("unbiased", "min", "median"):
-            for kcap in (0, 128, 512):
-                bn, bk = choose_decode_blocks(
-                    n, rb, vmem_budget=budget, r=r, estimator=est,
-                    kcap=kcap)
-                assert bk % 128 == 0 and bk >= 128
-                assert decode_tile_bytes(bn, bk, rb, r=r, estimator=est,
-                                         kcap=kcap) <= budget
-                assert bk >= kcap       # merge needs a kcap-wide block
-                bks[est, kcap] = bk
-        for kcap in (0, 128, 512):
-            assert bks["min", kcap] <= bks["unbiased", kcap]
-            assert bks["median", kcap] == bks["min", kcap]
-    # larger budget -> never narrower tiles
-    widths = [choose_decode_blocks(n, rb, vmem_budget=bud, r=r,
-                                   estimator="min")[1]
-              for bud in (4 * 2**20, 6 * 2**20, 32 * 2**20)]
-    assert widths == sorted(widths)
+            for kcap in (128, 512):
+                bn, bk, nh = choose_decode_blocks(
+                    n, r, b, vmem_budget=budget, estimator=est, kcap=kcap)
+                assert bk % 128 == 0 and bk >= 128 and 1 <= nh <= r
+                assert decode_tile_bytes(bn, bk, nh, b, estimator=est,
+                                         kcap=kcap, r=r) <= budget
+                tiles[est, kcap] = bk * nh
+        for kcap in (128, 512):
+            assert tiles["median", kcap] <= tiles["unbiased", kcap]
+    # K=1M retrieval setting: the whole (R, bn, B) block cannot fit, so
+    # the chooser tiles R rather than raise
+    bn, bk, nh = choose_decode_blocks(32, 16, 8192, kcap=128)
+    assert nh < 16
+    assert decode_tile_bytes(bn, bk, nh, 8192, kcap=128) <= 6 * 2**20
+    # larger budget -> never smaller tiles
+    sizes = [bk * nh for _, bk, nh in (
+        choose_decode_blocks(n, r, b, vmem_budget=bud, estimator="min")
+        for bud in (4 * 2**20, 6 * 2**20, 32 * 2**20))]
+    assert sizes == sorted(sizes)
     # floor overflow is an error, not a silent VMEM blowout ...
     with pytest.raises(ValueError):
-        choose_decode_blocks(n, rb, vmem_budget=2**18, r=r,
-                             estimator="min")
+        choose_decode_blocks(n, r, b, vmem_budget=2**17,
+                             estimator="median")
     # ... unless the caller takes responsibility with an explicit block_k
-    assert choose_decode_blocks(32, rb, None, 256,
-                                vmem_budget=2**18) == (32, 256)
+    assert choose_decode_blocks(32, r, b, None, 256,
+                                vmem_budget=2**17) == (32, 256, r)
 
 
 @pytest.mark.parametrize("block_n", [5, 13])
