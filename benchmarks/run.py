@@ -22,7 +22,6 @@ Prints ``name,us_per_call,derived`` CSV lines (shared report hook).
                     parity + no-max_len-strip jaxpr gate), and
                     sustained Poisson traffic (p50/p99 latency ticks,
                     tokens/step) — also writes BENCH_serve.json
-  roofline          §Roofline aggregation from the dry-run artifacts
 """
 
 from __future__ import annotations
@@ -47,7 +46,7 @@ def main() -> int:
 
     from benchmarks import (bench_decode_topk, bench_kernels, bench_serve,
                             bench_sparse_xent, bench_train_xent,
-                            fig1_tradeoff, roofline, table2_resources,
+                            fig1_tradeoff, table2_resources,
                             table3_estimators)
     modules = {
         "table2_resources": table2_resources,
@@ -57,7 +56,6 @@ def main() -> int:
         "bench_train_xent": bench_train_xent,
         "bench_sparse_xent": bench_sparse_xent,
         "bench_serve": bench_serve,
-        "roofline": roofline,
         "fig1_tradeoff": fig1_tradeoff,
     }
     failed = []

@@ -21,6 +21,8 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from repro.kernels import phase
+
 ESTIMATORS = ("unbiased", "min", "median")
 
 
@@ -78,6 +80,7 @@ def predict_classes(meta_probs: jnp.ndarray, table: jnp.ndarray,
     return jnp.argmax(estimate_class_probs(meta_probs, table, estimator), axis=-1)
 
 
+@phase.tagged(phase.DECODE_TOPK)
 def predict_topk(meta_probs: jnp.ndarray, table: jnp.ndarray, k: int,
                  estimator: str = "unbiased", *,
                  candidate_mode=None,

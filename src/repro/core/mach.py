@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.core import estimators as est
 from repro.core import hashing
+from repro.kernels import phase
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,6 +219,7 @@ class MACHHead(abc.ABC):
         return mach_loss(self.head_logits(params, inputs),
                          self.cfg.hash_labels(labels), weights)
 
+    @phase.tagged(phase.DECODE_PROJECT)
     def meta_probs(self, params: dict, inputs: Any) -> jnp.ndarray:
         """getProbability of Algorithm 2: (R, ..., B)."""
         return mach_meta_probs(self.head_logits(params, inputs))
@@ -305,6 +307,7 @@ class MACHLinear(MACHHead):
             return self.fused_loss(params, x, y, weights)
         return super().loss(params, x, y, weights)
 
+    @phase.tagged(phase.LOSS_FWD)
     def fused_loss(self, params: dict, x: Any, y: jnp.ndarray,
                    weights: Optional[jnp.ndarray] = None,
                    bucket_select: Optional[tuple] = None,
@@ -406,6 +409,7 @@ class MACHOutputHead(MACHHead):
     def head_logits(self, params: dict, h: jnp.ndarray) -> jnp.ndarray:
         return self.apply(params, h)
 
+    @phase.tagged(phase.LOSS_FWD)
     def fused_loss(self, params: dict, h: jnp.ndarray, labels: jnp.ndarray,
                    weights: Optional[jnp.ndarray] = None,
                    bucket_select: Optional[tuple] = None,

@@ -60,6 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.estimators import ESTIMATORS
+from repro.kernels import phase
 from repro.kernels.mach_decode import LANE as _LANE
 from repro.kernels.mach_decode import (NEG_INF, gather_rep, median_of,
                                        merge_topk, round_up)
@@ -162,6 +163,8 @@ def bucket_topm_pallas(meta_probs: jnp.ndarray, m: int,
         out_shape=(jax.ShapeDtypeStruct((n, r, mpad), jnp.int32),
                    jax.ShapeDtypeStruct((n, r, 1), jnp.float32)),
         interpret=interpret,
+        name="mach_bucket_topm",
+        metadata=phase.kernel_metadata(phase.DECODE_TOPK),
     )(meta_probs)
     return tau[:, :, 0], ids[:, :, :m]
 
@@ -420,6 +423,8 @@ def mach_candidate_topk_pallas(meta_probs: jnp.ndarray,
         out_shape=(jax.ShapeDtypeStruct((n, 1, kcap), jnp.float32),
                    jax.ShapeDtypeStruct((n, 1, kcap), jnp.int32)),
         interpret=interpret,
+        name="mach_candidate_topk",
+        metadata=phase.kernel_metadata(phase.DECODE_TOPK),
     )(chunks, coeffs, meta_probs, tau[:, :, None], inverted[:, None, :])
 
     return decode_penalty_topk(val[:, 0, :k], idx[:, 0, :k], t)

@@ -46,6 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import phase
+
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 LANE = 128                # running-top-k capacity granularity
 _B_SLICE = 2048           # buckets per one-hot slice (VMEM bound at big B)
@@ -310,6 +312,8 @@ def streaming_topk(meta_probs: jnp.ndarray,
                    jax.ShapeDtypeStruct((npad, kcap), jnp.int32)),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="mach_topk",
+        metadata=phase.kernel_metadata(phase.DECODE_TOPK),
     )(probs, hash_arg)
     return val[:n, :k], idx[:n, :k]
 

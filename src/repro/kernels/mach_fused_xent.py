@@ -122,6 +122,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import phase
 from repro.kernels.mach_decode import NEG_INF, round_up
 
 _LANE = 128
@@ -134,6 +135,15 @@ _SEQUENTIAL3 = pltpu.CompilerParams(
     dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
 _SEQUENTIAL2 = pltpu.CompilerParams(
     dimension_semantics=("arbitrary", "arbitrary"))
+
+
+def _kernel_tags(family: str, kind: str) -> dict:
+    """``name=`` and ``metadata=`` of one family's forward or backward
+    kernel: the phase tag of ``phase.py``."""
+    return dict(name=f"mach_fused_xent_{family}_{kind}",
+                metadata=phase.kernel_metadata(
+                    phase.LOSS_FWD if kind == "fwd" else phase.LOSS_BWD))
+
 
 DEFAULT_VMEM_BUDGET = 6 * 2**20
 
@@ -833,6 +843,7 @@ def _fused_call(kind, h2p, wp, biasp, yp, lsep, gp, dims, bn, bc, bd,
             + [pltpu.VMEM((bn, rp), jnp.float32)] * 3,
             compiler_params=_SEQUENTIAL3,
             interpret=interpret,
+            **_kernel_tags("dense", kind),
         )(h2p, wp, biasp, yp)
     # bwd: column blocks outer, then the two phases of ``_bwd_cell``
     nrows = npad // bn
@@ -867,6 +878,7 @@ def _fused_call(kind, h2p, wp, biasp, yp, lsep, gp, dims, bn, bc, bd,
                         pltpu.VMEM((npad, bc), jnp.float32)],
         compiler_params=_SEQUENTIAL2,
         interpret=interpret,
+        **_kernel_tags("dense", kind),
     )(h2p, wp, biasp, yp, lsep, gp)
 
 
@@ -886,6 +898,7 @@ def _check_shapes(h2, w, bias, hashed_labels, num_buckets):
     return n, d, r
 
 
+@phase.tagged(phase.LOSS_FWD)
 def _fused_fwd(h2, w, bias, hashed_labels, num_buckets, block_n, block_c,
                block_d, interpret):
     n, d, r = _check_shapes(h2, w, bias, hashed_labels, num_buckets)
@@ -900,6 +913,7 @@ def _fused_fwd(h2, w, bias, hashed_labels, num_buckets, block_n, block_c,
     return loss[:n, 0], (h2, w, bias, hashed_labels, lse[:n])
 
 
+@phase.tagged(phase.LOSS_BWD)
 def _fused_bwd(num_buckets, block_n, block_c, block_d, interpret, res, g):
     h2, w, bias, hashed_labels, lse = res
     n, d, r = _check_shapes(h2, w, bias, hashed_labels, num_buckets)
@@ -985,6 +999,7 @@ def _sparse_call(kind, colsp, valsp, wp, biasp, yp, lsep, gp, dims, bn,
             + [pltpu.VMEM((bn, rp), jnp.float32)] * 3,
             compiler_params=_SEQUENTIAL3,
             interpret=interpret,
+            **_kernel_tags("sparse", kind),
         )(colsp, valsp, wp, biasp, yp)
     # bwd: column blocks outer, then the two phases of ``_bwd_cell``
     nrows = npad // bn
@@ -1015,6 +1030,7 @@ def _sparse_call(kind, colsp, valsp, wp, biasp, yp, lsep, gp, dims, bn,
                         pltpu.VMEM((npad, bc), jnp.float32)],
         compiler_params=_SEQUENTIAL2,
         interpret=interpret,
+        **_kernel_tags("sparse", kind),
     )(colsp, valsp, wp, biasp, yp, lsep, gp)
 
 
@@ -1034,6 +1050,7 @@ def _check_sparse_shapes(cols, vals, w, bias, hashed_labels, num_buckets):
     return n, d, r, j
 
 
+@phase.tagged(phase.LOSS_FWD)
 def _sparse_fwd(cols, vals, w, bias, hashed_labels, num_buckets, block_n,
                 block_c, block_d, interpret):
     n, d, r, j = _check_sparse_shapes(cols, vals, w, bias, hashed_labels,
@@ -1049,6 +1066,7 @@ def _sparse_fwd(cols, vals, w, bias, hashed_labels, num_buckets, block_n,
     return loss[:n, 0], (cols, vals, w, bias, hashed_labels, lse[:n])
 
 
+@phase.tagged(phase.LOSS_BWD)
 def _sparse_bwd(num_buckets, block_n, block_c, block_d, interpret, res, g):
     cols, vals, w, bias, hashed_labels, lse = res
     n, d, r, j = _check_sparse_shapes(cols, vals, w, bias, hashed_labels,
@@ -1143,6 +1161,7 @@ def _gather_call(kind, colsp, valsp, wp, biasp, yp, lsep, gp, dims, bc,
                        jax.ShapeDtypeStruct((n, rp), jnp.float32)),
             compiler_params=_SEQUENTIAL3,
             interpret=interpret,
+            **_kernel_tags("gather", kind),
         )(colsp, valsp, wp, biasp, yp)
     # bwd: both phases of an (i, j) cell map the same gathered dW/W row
     kmap = lambda k2: jnp.where(k2 >= jp, k2 - jp, k2)
@@ -1169,10 +1188,12 @@ def _gather_call(kind, colsp, valsp, wp, biasp, yp, lsep, gp, dims, bc,
         input_output_aliases={7: 0, 8: 1},
         compiler_params=_SEQUENTIAL3,
         interpret=interpret,
+        **_kernel_tags("gather", kind),
     )(colsp, valsp, wp, biasp, yp, lsep, gp,
       jnp.zeros((d, c), jnp.float32), jnp.zeros((1, c), jnp.float32))
 
 
+@phase.tagged(phase.LOSS_FWD)
 def _gather_fwd(cols, vals, w, bias, hashed_labels, num_buckets, block_c,
                 interpret):
     n, d, r, j = _check_sparse_shapes(cols, vals, w, bias, hashed_labels,
@@ -1187,6 +1208,7 @@ def _gather_fwd(cols, vals, w, bias, hashed_labels, num_buckets, block_c,
     return loss[:, 0], (cols, vals, w, bias, hashed_labels, lse)
 
 
+@phase.tagged(phase.LOSS_BWD)
 def _gather_bwd(num_buckets, block_c, interpret, res, g):
     cols, vals, w, bias, hashed_labels, lse = res
     n, d, r, j = _check_sparse_shapes(cols, vals, w, bias, hashed_labels,

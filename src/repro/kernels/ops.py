@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref
+from repro.kernels import phase, ref
 from repro.kernels.mach_candidates import (mach_candidate_topk,
                                            mach_candidate_topk_pallas)
 from repro.kernels.mach_decode import mach_decode_pallas
@@ -61,6 +61,7 @@ def _table_from_inline(inline_coeffs: jnp.ndarray, inline_shift: int,
 # MACH decode
 # ---------------------------------------------------------------------------
 
+@phase.tagged(phase.DECODE_TOPK)
 def mach_top1(meta_probs: jnp.ndarray,
               table: Optional[jnp.ndarray] = None,
               *,
@@ -148,6 +149,7 @@ def _blocked_topk_fallback(flat: jnp.ndarray, table: jnp.ndarray, k: int,
     return val, idx
 
 
+@phase.tagged(phase.DECODE_TOPK)
 def mach_topk(meta_probs: jnp.ndarray,
               table: Optional[jnp.ndarray] = None,
               *,
@@ -214,6 +216,7 @@ def mach_topk(meta_probs: jnp.ndarray,
     return val.reshape(lead + (k,)), idx.reshape(lead + (k,))
 
 
+@phase.tagged(phase.DECODE_TOPK)
 def mach_topk_candidates(meta_probs: jnp.ndarray,
                          table: Optional[jnp.ndarray] = None,
                          *,
@@ -336,6 +339,7 @@ def csr_to_ell(indptr: jnp.ndarray, indices: jnp.ndarray,
     return cols, vals
 
 
+@phase.tagged(phase.LOSS_FWD)
 def mach_fused_xent_csr(indptr: jnp.ndarray, indices: jnp.ndarray,
                         values: jnp.ndarray, w: jnp.ndarray,
                         hashed_labels: jnp.ndarray,
@@ -442,6 +446,7 @@ def mach_fused_xent_csr(indptr: jnp.ndarray, indices: jnp.ndarray,
         num_buckets, block_n, block_c, block_d, interp)
 
 
+@phase.tagged(phase.LOSS_FWD)
 def mach_fused_xent(h: jnp.ndarray, w: jnp.ndarray,
                     hashed_labels: jnp.ndarray,
                     *, num_buckets: int,

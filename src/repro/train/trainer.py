@@ -18,6 +18,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import phase
 from repro.optim import (accumulate_grads, apply_updates,
                          clip_by_global_norm, make_optimizer, make_schedule)
 from repro.train.train_state import TrainState, new_train_state
@@ -80,12 +81,15 @@ def make_head_step(loss_fn: Callable, opt):
     """The jitted step of a MACH head trained on its own (the paper's
     logistic-regression runs): ``loss_fn(params, x, y)`` -> scalar;
     returns ``step(params, opt_state, x, y) -> (params, opt_state,
-    loss)``.  params and opt_state are donated — updated in place."""
+    loss)``.  params and opt_state are donated — updated in place.  The
+    update's ops carry the ``optim`` phase tag; the loss tags its own."""
 
     def step(params, opt_state, x, y):
         loss, grads = jax.value_and_grad(loss_fn)(params, x, y)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return apply_updates(params, updates), opt_state, loss
+        with phase.tag(phase.OPTIM):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
+        return params, opt_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1))
 

@@ -8,14 +8,21 @@ which refuses what interpret mode cannot see — unaligned blocks,
 lane-splitting reshapes, ops with no TPU lowering, VMEM overflow.  Each
 test asserts that the compiled program holds the kernel
 (``tpu_custom_call``), so a dispatch that silently fell back to jnp
-fails here.
+fails here, and that a MACH kernel carries its program phase
+(``kernels/phase.py``) in its frontend attributes, where the device
+trace reads it.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU compiler library at a time, and the
 test workers all import this file.
 """
 
+import collections
+import contextlib
 import functools
+import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -71,8 +78,44 @@ def _compile(fn, sharding, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _assert_kernel(text):
+def _kernel_phases(text):
+    """The ``mach_phase`` of each Mosaic custom call in a compiled text
+    ("" where it has none)."""
+    calls = [ins for ins in re.split(r"\n\s+(?=(?:ROOT )?%)", text)
+             if 'custom_call_target="tpu_custom_call"' in ins]
+    return [m.group(1) if (m := re.search(r'"mach_phase":"([^"]+)"', ins))
+            else "" for ins in calls]
+
+
+# Entry ops that do no device work of their own, and the copies XLA
+# adds to move data between layouts (the trace counts copies as
+# untagged time; PERF.md names them).
+_FREE_OPS = {"parameter", "constant", "tuple", "get-tuple-element",
+             "bitcast", "copy", "copy-start", "copy-done"}
+
+
+def _untagged_ops(text):
+    """The entry computation's ops with no ``mach_phase``, bar
+    ``_FREE_OPS``, counted by name without its numeric suffix.  These
+    instructions are the op events of the device trace."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    out = collections.Counter()
+    for ins in re.split(r"\n\s+(?=(?:ROOT )?%)", entry)[1:]:
+        name, op = re.match(r"(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\(",
+                            ins).groups()
+        assert op not in ("while", "conditional", "call"), ins[:120]
+        if op not in _FREE_OPS and "mach_phase" not in ins:
+            out[re.sub(r"\.\d+$", "", name)] += 1
+    return out
+
+
+def _assert_kernel(text, *phases):
+    """The program holds a kernel; with ``phases``, its kernels carry
+    exactly these."""
     assert "tpu_custom_call" in text
+    if phases:
+        assert sorted(set(_kernel_phases(text))) == sorted(phases)
 
 
 # ---------------------------------------------------------------------------
@@ -91,7 +134,7 @@ def test_sparse_fused_xent_odp(one_chip):
                     ((n, ODP_NNZ), jnp.int32), ((n, ODP_NNZ), jnp.float32),
                     ((ODP_D, ODP_R * ODP_B), jnp.float32),
                     ((ODP_R * ODP_B,), jnp.float32), ((n, ODP_R), jnp.int32))
-    _assert_kernel(text)
+    _assert_kernel(text, "loss.fwd", "loss.bwd")
 
 
 def test_dense_fused_xent_lm_head(one_chip):
@@ -105,7 +148,7 @@ def test_dense_fused_xent_lm_head(one_chip):
                     ((n, LM_D), jnp.float32),
                     ((LM_D, LM_R * LM_B), jnp.float32),
                     ((n, LM_R), jnp.int32))
-    _assert_kernel(text)
+    _assert_kernel(text, "loss.fwd", "loss.bwd")
 
 
 @pytest.mark.xfail(strict=True, reason=(
@@ -151,7 +194,7 @@ def test_topk_odp_table(one_chip, estimator):
                            estimator=estimator)
     text = _compile(fn, one_chip, ((256, ODP_R, ODP_B), jnp.float32),
                     ((ODP_R, ODP_K), jnp.int32))
-    _assert_kernel(text)
+    _assert_kernel(text, "decode.topk")
 
 
 @pytest.mark.parametrize("estimator", ["unbiased", "min", "median"])
@@ -164,7 +207,7 @@ def test_topk_lm_inline(one_chip, estimator):
                                 inline_shift=21)
     text = _compile(fn, one_chip, ((8, LM_R, LM_B), jnp.float32),
                     ((LM_R,), jnp.uint32))
-    _assert_kernel(text)
+    _assert_kernel(text, "decode.topk")
 
 
 def test_topk_retrieval_1m(one_chip):
@@ -175,7 +218,7 @@ def test_topk_retrieval_1m(one_chip):
                                 inline_coeffs=coeffs, inline_shift=19)
     text = _compile(fn, one_chip, ((32, 16, 8192), jnp.float32),
                     ((16,), jnp.uint32))
-    _assert_kernel(text)
+    _assert_kernel(text, "decode.topk")
 
 
 def test_top1_lm_inline(one_chip):
@@ -184,7 +227,7 @@ def test_top1_lm_inline(one_chip):
                                   inline_coeffs=coeffs, inline_shift=21)
     text = _compile(fn, one_chip, ((8, LM_R, LM_B), jnp.float32),
                     ((LM_R,), jnp.uint32))
-    _assert_kernel(text)
+    _assert_kernel(text, "decode.topk")
 
 
 def test_candidate_topk(one_chip):
@@ -197,7 +240,121 @@ def test_candidate_topk(one_chip):
             inline_coeffs=coeffs, inline_shift=19)
     text = _compile(fn, one_chip, ((64, r, b), jnp.float32),
                     ((r * b, ell), jnp.int32), ((r,), jnp.uint32))
-    _assert_kernel(text)
+    _assert_kernel(text, "decode.topk")
+
+
+# ---------------------------------------------------------------------------
+# the whole MACH head step: phase tags, and a program they leave unchanged
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("features", ["csr", "dense"])
+def test_head_step_phases(one_chip, monkeypatch, features):
+    """The benchmark's head step (``MACHLinear(fused=True)``, AdamW,
+    ``make_head_step``) at a tiny size through the TPU dispatch: its
+    compiled ops carry the three training phases, and compiled with the
+    tags switched off it is the same program up to names and
+    annotations (``tools/compiled_hlo.py``)."""
+    from repro.core import MACHConfig, MACHLinear
+    from repro.data.extreme import SparseBatch
+    from repro.kernels import mach_fused_xent, ops, phase
+    from repro.optim import adamw
+    from repro.train.trainer import make_head_step
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    from compiled_hlo import normalize
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    n, d, r, b, nnz = 64, 1024, 4, 32, 8
+    model = MACHLinear(MACHConfig(num_classes=1000, num_buckets=b,
+                                  num_repetitions=r, hash_kind="mult_shift"),
+                       d, fused=True)
+    opt = adamw(1e-3)
+
+    def shape(s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    params = {"w": shape((d, r, b)), "b": shape((r, b))}
+    state = jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                         jax.eval_shape(opt.init, params))
+    x = (SparseBatch(shape((n + 1,), jnp.int32), shape((n * nnz,), jnp.int32),
+                     shape((n * nnz,)), num_features=d, nnz_max=nnz)
+         if features == "csr" else shape((n, d)))
+    y = shape((n,), jnp.int32)
+
+    def compiled():
+        with jax.default_matmul_precision("default"):
+            return make_head_step(model.loss, opt).lower(
+                params, state, x, y).compile().as_text()
+
+    tagged = compiled()
+    assert set(re.findall(r'mach_phase="([\w.]+)"', tagged)) == {
+        "loss.fwd", "loss.bwd", "optim"}
+    # every op but two carries its phase: XLA rebuilds the CSR->ELL
+    # gather of the column ids and carries no tag over
+    assert _untagged_ops(tagged) == (
+        {"fusion": 1, "pad_clamp_fusion": 1} if features == "csr" else {})
+    monkeypatch.setattr(phase, "tag", lambda p: contextlib.nullcontext())
+    monkeypatch.setattr(mach_fused_xent, "_kernel_tags", lambda *a: {})
+    plain = compiled()
+    assert "mach_phase" not in plain
+    assert normalize(tagged) == normalize(plain)
+
+
+# Untagged ops of the decode cells' calls (N = 256, top-10), pinned.
+# In ODP's meta_probs XLA rewrites the densify's 2-D scatter-add into a
+# 1-D one over the flattened (N * d) buffer, and none of the ops it
+# makes carries the tag: the flat zero fill (``broadcast``), the scatter
+# fusion (``fusion``), the relayout back to (N, d) for the projection
+# (``reshape``), the linear index and its bounds check (the
+# ``*_reduce_fusion``, ``broadcast_clamp_fusion`` and
+# ``broadcast_select_fusion``); the cumulative sums behind
+# ``jnp.repeat``'s row ids come apart into untagged ``reduce-window``,
+# ``slice`` and small fusions.  In predict_topk the table's pad becomes
+# an untagged reshape (named after the ``broadcast_in_dim`` it was).
+DECODE_UNTAGGED = {
+    "odp": ({"broadcast": 2, "fusion": 1, "reshape": 1,
+             "broadcast_clamp_fusion": 1, "and_reduce_fusion": 1,
+             "multiply_reduce_fusion": 1, "broadcast_select_fusion": 1,
+             "reduce-window": 5, "slice": 3, "slice_reduce_fusion": 2,
+             "add_bitcast_fusion": 1, "pad_bitcast_fusion": 1,
+             "pad_slice_fusion": 1, "broadcast_add_fusion": 1},
+            {"broadcast_in_dim": 1}),
+    "imagenet21k": ({}, {"broadcast_in_dim": 1}),
+}
+
+
+@pytest.mark.parametrize("cell", ["odp", "imagenet21k"])
+def test_decode_untagged_ops(one_chip, monkeypatch, cell):
+    """The benchmark's decode calls at the decode cells' widths and
+    precision, compiled for the chip: which ops lose their phase."""
+    from repro.core import MACHConfig, MACHLinear, estimators
+    from repro.data.extreme import SparseBatch
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    n, k, (classes, d, b, r) = 256, 10, {
+        "odp": (ODP_K, ODP_D, ODP_B, ODP_R),
+        "imagenet21k": (21_841, 6144, 512, 20)}[cell]
+    model = MACHLinear(MACHConfig(num_classes=classes, num_buckets=b,
+                                  num_repetitions=r, hash_kind="mult_shift"),
+                       d, fused=True)
+
+    def shape(s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    x = (SparseBatch(shape((n + 1,), jnp.int32),
+                     shape((n * ODP_NNZ,), jnp.int32),
+                     shape((n * ODP_NNZ,)), num_features=d, nnz_max=ODP_NNZ)
+         if cell == "odp" else shape((n, d)))
+    topk = functools.partial(estimators.predict_topk, k=k,
+                             estimator="unbiased")
+    with jax.default_matmul_precision("highest"):
+        meta = jax.jit(model.meta_probs).lower(
+            {"w": shape((d, r, b)), "b": shape((r, b))}, x).compile()
+        ids = jax.jit(topk).lower(shape((r, n, b)),
+                                  shape((r, classes), jnp.int32)).compile()
+    assert (_untagged_ops(meta.as_text()), _untagged_ops(ids.as_text())) \
+        == DECODE_UNTAGGED[cell]
 
 
 # ---------------------------------------------------------------------------
