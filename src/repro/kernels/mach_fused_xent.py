@@ -7,7 +7,7 @@ the paper's O(d log K) story holds only for parameters.  This kernel
 fuses the hidden→bucket projection into the loss itself.
 
 Both the dense-h and the sparse-h (padded-ELL) families share one
-d-blocked structure:
+d-blocked forward:
 
     forward grid (N/bn, C/bc, D/bd), C = R·B columns, d minor.  W
     streams through (bd, bc) VMEM tiles and h through (bn, bd) slices
@@ -27,24 +27,24 @@ logsumexp completes in its block); when B is larger than the budget a
 block is a bucket-slice of a single head and the online update streams
 the head's logsumexp across blocks.  Both cases run the same body.
 
-The custom VJP recomputes each logits tile ONCE (the standard fused-CE
+The custom VJPs recompute each logits tile ONCE (the standard fused-CE
 trade) from the saved per-head logsumexp:
 
     dlogits[n, rB+b] = g_n · (softmax(logits)[n, r, b] − 1[b = y_nr])
 
-in a single kernel, grid (C/bc, 2·rows/bn·D/bd) with two phases per
-column block.  Phase 1 sweeps the d blocks inside each row block,
+Dense h: a single kernel, grid (C/bc, 2·rows/bn·D/bd) with two phases
+per column block.  Phase 1 sweeps the d blocks inside each row block,
 rebuilding its logits tile once; at the last d block it forms dlogits
 into a (rows, bc) VMEM scratch and reduces dbias into the (1, bc)
 output row.  Phase 2 sweeps the row blocks inside each d block:
-``dW_blk += a_kᵀ @ dlogits`` accumulates in the (bd, bc) output block
-over consecutive steps, and — dense h only — ``dh_blk = dlogits @
-W_kᵀ`` is written per column block (the wrapper sums the C/bc
-partials).  No output block is revisited after its index moved away: a
-TPU writes an output block back when its index changes and never
-re-reads it.  Batches beyond ``_BWD_ROWS`` rows run the backward once
-per row chunk.  Activation residuals are the inputs and the (N, R)
-logsumexp — O(N·d) dense / O(N·J) sparse, independent of R·B.
+``dW_blk += h_kᵀ @ dlogits`` accumulates in the (bd, bc) output block
+over consecutive steps, and ``dh_blk = dlogits @ W_kᵀ`` is written per
+column block (the wrapper sums the C/bc partials).  No output block is
+revisited after its index moved away: a TPU writes an output block back
+when its index changes and never re-reads it.  Batches beyond
+``_BWD_ROWS`` rows run either family's backward once per row chunk.
+Activation residuals are the inputs and the (N, R) logsumexp — O(N·d)
+dense / O(N·J) sparse, independent of R·B.
 
 The per-head reductions work on the 2-D (bn, bc) tile with one lane
 mask per head — never a (bn, nh, width) reshape, which splits lanes
@@ -57,12 +57,24 @@ Sparse features (the paper's ODP d=422k workload): the ``*_sparse``
 entry points take the batch in padded-ELL form — ``cols/vals (N, J)``,
 row n's features at ``cols[n, :]`` with weights ``vals[n, :]`` (padding
 carries val 0) — as produced from CSR by ``ops.mach_fused_xent_csr``.
-Per d block the active slice of the activation is densified *in VMEM*
+The forward densifies each d block's slice of the activation *in VMEM*
 via a one-hot contraction (``A[n, p] = Σ_j vals[n, j]·1[cols[n, j] =
 d0+p]``, MXU/Mosaic-friendly, duplicate ids sum like a CSR scatter-add);
-the dense (N, d) activation never exists in HBM.  ``vals`` is treated
-as non-differentiable data (zero cotangent): features are inputs, not
-parameters.
+the dense (N, d) activation never exists in HBM.  The backward works
+from the batch's entries instead of sweeping d per row block: the
+wrapper sorts the flat (col, row, val) entries by col (sentinel slots
+past the last block) and cuts them into work items, each inside one d
+block and one SMEM chunk of entries, every d block holding at least
+one.  One kernel walks the items twice per column block: the logits
+phase adds ``val · W[col]`` into the entry's row of a (rows, bc) VMEM
+scratch, reading each (bd, bc) W block once, then turns the scratch
+into dlogits and dbias; the dW phase zeroes each (bd, bc) dW block at
+its first item, adds ``val · dlogits[row]`` into the entry's row of
+it, and writes it once.  Both are dynamic row loads and row adds in
+f32 on the VPU; duplicate ids sum.  A DMA of a single W row is not an
+option: W's HBM layout is tiled (8, 128), and Mosaic slices it only
+at tile bounds.  ``vals`` is treated as non-differentiable data (zero
+cotangent): features are inputs, not parameters.
 
 Scalar-prefetch gather (the high-nnz sparse path): the one-hot
 densification pays O(bn·jp·bd) VMEM and compute per step, which makes
@@ -92,10 +104,12 @@ keep bc large first — each column block pays a full densify d-sweep —
 then bd, shrinking bn before bd as the one-hot tile grows) and return
 the first whose accounted tile bytes (``dense_tile_bytes`` /
 ``sparse_tile_bytes`` — the superset of either pass's resident VMEM
-tiles) fit ``vmem_budget``.  A ``ValueError`` is raised only when even
-the minimum tiling (bn=8, bc=128, bd at its floor) overflows; explicit
-``block_*`` overrides pin their dimension and the rest shrink around
-them.
+tiles) fit ``vmem_budget``.  The sparse backward keeps the forward's
+column blocks and padded W and picks its own d block
+(``choose_sorted_bwd_blocks`` / ``sorted_bwd_tile_bytes``).  A
+``ValueError`` is raised only when even the minimum tiling (bn=8,
+bc=128, bd at its floor) overflows; explicit ``block_*`` overrides pin
+their dimension and the rest shrink around them.
 
 Padding: N pads to bn (padded rows get zero cotangent so contribute
 nothing), d pads to a multiple of bd (zero h columns / zero W rows
@@ -186,7 +200,10 @@ def sparse_tile_bytes(bn: int, bc: int, bd: int, rp: int, jp: int,
     """Accounted VMEM bytes of the sparse kernels' resident tiles (f32).
     The per-step densify holds ~two (bn, jp, bd) one-hot intermediates
     on top of the ELL tiles; otherwise as ``dense_tile_bytes`` minus the
-    dense dh output."""
+    dense dh output.  The ``bwd`` term is that of the d-sweep backward
+    the sparse family had; it still bounds the forward's tiling, which
+    stays where it was measured.  The backward now accounts its tiles
+    in ``sorted_bwd_tile_bytes``."""
     densify = 2 * bn * jp * bd + 2 * bn * jp
     fwd = densify + bd * bc + bc + 5 * bn * rp + bn + bn * bc
     bwd = densify + 2 * bd * bc + 2 * bc + 2 * bn * rp + 2 * bn \
@@ -292,6 +309,41 @@ def choose_sparse_blocks(n: int, d: int, r: int, b: int, j: int,
     raise ValueError(
         f"no sparse fused-xent tiling fits vmem_budget={vmem_budget} "
         f"(n={n}, d={d}, r={r}, b={b}, nnz_max={j} -> jp={jp})")
+
+
+# Sorted entries per SMEM block of the sparse backward.
+_ENTRY_CHUNK = 1024
+
+
+def sorted_bwd_tile_bytes(bc: int, bd: int, rp: int, rows: int) -> int:
+    """Accounted VMEM bytes of the sparse backward's resident tiles
+    (f32): the logits / dlogits scratch of every row of one call
+    (rows, bc); the W and dW blocks 4·(bd, bc) and bias and dbias
+    4·(1, bc), double-buffered; labels and logsumexp 4·(rows, rp) and
+    g 2·(rows, 1).  The sorted entries and the work items ride in SMEM."""
+    return 4 * (rows * bc + 4 * bd * bc + 4 * bc + 4 * rows * rp
+                + 2 * rows)
+
+
+def choose_sorted_bwd_blocks(n: int, d: int, bc: int, rp: int,
+                             block_d: Optional[int] = None
+                             ) -> tuple[int, int]:
+    """Pick (bd, rows) for the sparse backward, whose column blocks
+    (bc, rp) are the forward's (``choose_sparse_blocks``), so that both
+    passes read one padded W: the largest d block whose
+    ``sorted_bwd_tile_bytes`` fit ``DEFAULT_VMEM_BUDGET`` (fewer grid
+    steps; W and dW move in (bd, bc) blocks either way), and ``rows``
+    the rows per backward call (``_BWD_ROWS`` at most).  ``block_d``
+    pins bd, as in ``choose_sparse_blocks``.  Raises ``ValueError`` when
+    even bd = 8 overflows."""
+    rows = _bwd_rows(n, 8)
+    for bd in _candidates(block_d, (256, 128, 64, 32, 16, 8),
+                          min(512, round_up(max(d, 1), 8)), 8):
+        if sorted_bwd_tile_bytes(bc, bd, rp, rows) <= DEFAULT_VMEM_BUDGET:
+            return bd, rows
+    raise ValueError(
+        f"no sparse backward tiling fits {DEFAULT_VMEM_BUDGET} bytes of "
+        f"VMEM (n={n}, d={d}, bc={bc}, rp={rp}, rows={rows})")
 
 
 # nnz at/above which ops.mach_fused_xent_csr auto-routes to the gather
@@ -701,15 +753,81 @@ def _sparse_fwd_body(bn, bc, bd, r, rp, b, bp, jp,
                        loss_ref, lse_ref, acc_scr, m_scr, s_scr, p_scr)
 
 
-def _sparse_bwd_body(bn, bc, bd, nrows, nkd, r, b, bp, jp,
-                     cols_ref, vals_ref, w_ref, bias_ref, y_ref, lse_ref,
-                     g_ref, dw_ref, db_ref, acc_scr, dlog_scr):
-    """No dh: ``vals`` is data (zero cotangent)."""
-    _, _, kd = _bwd_cell(pl.program_id(1), nrows, nkd)
-    a = _densify_tile(cols_ref, vals_ref, kd * bd, bn, jp, bd)
-    _dblocked_bwd_step(a, nrows, nkd, bn, bc, r, b, bp, w_ref, bias_ref,
-                       y_ref, lse_ref, g_ref, dw_ref, db_ref, acc_scr,
-                       dlog_scr)
+# Rows of the work-item table the sparse backward prefetches into SMEM
+# (``_work_items``): the item's d block, its entry chunk and the entry
+# range [lo, hi) inside the chunk.
+_BLK, _CHUNK, _LO, _HI = range(4)
+
+
+def _sorted_bwd_body(nitems, rows, bc, r, b, bp,
+                     items_ref, lc_ref, row_ref, val_ref, w_ref, bias_ref,
+                     y_ref, lse_ref, g_ref, dw_ref, db_ref, acc_scr):
+    """Sparse backward; grid (C/bc, 2·nitems), the work items of
+    ``_work_items`` twice over.  The entries sit in SMEM, sorted by
+    feature; an item names its d block k and the range of its entries
+    in the current chunk, each with a local col (col - k·bd), a row and
+    a val.
+
+    Logits phase (first pass): for each entry, ``acc[row] += val ·
+    W_k[col]``, a dynamic row load of the (bd, bc) W block and a row
+    add on the (rows, bc) scratch; W passes through VMEM once, not once
+    per row block.  At the last item the scratch holds every row's
+    logits; it is turned into dlogits in place, 8 rows at a time, and
+    dbias is their column sum.  dW phase (second pass): the (bd, bc)
+    output block of item k is zeroed at the block's first item, then
+    takes ``dW_k[col] += val · dlogits[row]`` for each entry, and is
+    written back once when the next block begins.  Duplicate ids,
+    within a row or across rows, add in turn."""
+    jblk = pl.program_id(0)
+    p = pl.program_id(1)
+    grad = p >= nitems
+    t = jnp.where(grad, p - nitems, p)
+    lo, hi = items_ref[_LO, t], items_ref[_HI, t]
+
+    @pl.when(p == 0)
+    def _init():
+        acc_scr[...] = jnp.zeros((rows, bc), jnp.float32)
+
+    @pl.when(jnp.logical_not(grad))
+    def _logits_phase():
+        def add(e, carry):
+            row = pl.ds(row_ref[e], 1)
+            w_row = w_ref[pl.ds(lc_ref[e], 1), :].astype(jnp.float32)
+            acc_scr[row, :] += val_ref[e] * w_row
+            return carry
+
+        jax.lax.fori_loop(lo, hi, add, 0)
+
+        @pl.when(p == nitems - 1)
+        def _dlogits():
+            def group(i, db):
+                sl = pl.ds(pl.multiple_of(i * 8, 8), 8)
+                tile, bidx, heads, h0 = _column_block(
+                    acc_scr[sl, :], bias_ref, b, bc, bp, jblk)
+                dlog = _dlogits_from_tile(tile, bidx, heads, y_ref.at[sl],
+                                          lse_ref.at[sl], g_ref.at[sl], r,
+                                          b, h0)
+                acc_scr[sl, :] = dlog
+                return db + jnp.sum(dlog, axis=0, keepdims=True)
+
+            db_ref[...] = jax.lax.fori_loop(
+                0, rows // 8, group, jnp.zeros((1, bc), jnp.float32))
+
+    @pl.when(grad)
+    def _grad_phase():
+        first = (t == 0) | (items_ref[_BLK, t]
+                            != items_ref[_BLK, jnp.maximum(t - 1, 0)])
+
+        @pl.when(first)
+        def _zero():
+            dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.float32)
+
+        def add(e, carry):
+            col = pl.ds(lc_ref[e], 1)
+            dw_ref[col, :] += val_ref[e] * acc_scr[pl.ds(row_ref[e], 1), :]
+            return carry
+
+        jax.lax.fori_loop(lo, hi, add, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -976,62 +1094,28 @@ def mach_fused_xent_sparse_pallas(cols: jnp.ndarray, vals: jnp.ndarray,
     return out
 
 
-def _sparse_call(kind, colsp, valsp, wp, biasp, yp, lsep, gp, dims, bn,
-                 bc, bd, jp, interpret):
-    """Shared pallas_call builder for the sparse forward/backward."""
+def _sparse_call(colsp, valsp, wp, biasp, yp, dims, bn, bc, bd, jp,
+                 interpret):
+    """pallas_call of the sparse forward."""
     npad, dp, r, rp, b, bp, c = dims
-    nkd = dp // bd
-    if kind == "fwd":
-        ell_spec = pl.BlockSpec((bn, jp), lambda i, j, k: (i, 0))
-        w_spec = pl.BlockSpec((bd, bc), lambda i, j, k: (k, j))
-        b_spec = pl.BlockSpec((1, bc), lambda i, j, k: (0, j))
-        row_spec = lambda width: pl.BlockSpec((bn, width),
-                                              lambda i, j, k: (i, 0))
-        return pl.pallas_call(
-            functools.partial(_sparse_fwd_body, bn, bc, bd, r, rp, b, bp,
-                              jp),
-            grid=(npad // bn, c // bc, nkd),
-            in_specs=[ell_spec, ell_spec, w_spec, b_spec, row_spec(rp)],
-            out_specs=(row_spec(1), row_spec(rp)),
-            out_shape=(jax.ShapeDtypeStruct((npad, 1), jnp.float32),
-                       jax.ShapeDtypeStruct((npad, rp), jnp.float32)),
-            scratch_shapes=[pltpu.VMEM((bn, bc), jnp.float32)]
-            + [pltpu.VMEM((bn, rp), jnp.float32)] * 3,
-            compiler_params=_SEQUENTIAL3,
-            interpret=interpret,
-            **_kernel_tags("sparse", kind),
-        )(colsp, valsp, wp, biasp, yp)
-    # bwd: column blocks outer, then the two phases of ``_bwd_cell``
-    nrows = npad // bn
-
-    def cell(p):
-        return _bwd_cell(p, nrows, nkd)
-
-    def dw_index(j, p):
-        grad, _, kd = cell(p)
-        return jnp.where(grad, kd, 0), j
-
-    ell_spec = pl.BlockSpec((bn, jp), lambda j, p: (cell(p)[1], 0))
+    ell_spec = pl.BlockSpec((bn, jp), lambda i, j, k: (i, 0))
+    w_spec = pl.BlockSpec((bd, bc), lambda i, j, k: (k, j))
+    b_spec = pl.BlockSpec((1, bc), lambda i, j, k: (0, j))
     row_spec = lambda width: pl.BlockSpec((bn, width),
-                                          lambda j, p: (cell(p)[1], 0))
+                                          lambda i, j, k: (i, 0))
     return pl.pallas_call(
-        functools.partial(_sparse_bwd_body, bn, bc, bd, nrows, nkd, r, b,
-                          bp, jp),
-        grid=(c // bc, 2 * nrows * nkd),
-        in_specs=[ell_spec, ell_spec,
-                  pl.BlockSpec((bd, bc), lambda j, p: (cell(p)[2], j)),
-                  pl.BlockSpec((1, bc), lambda j, p: (0, j)),
-                  row_spec(rp), row_spec(rp), row_spec(1)],
-        out_specs=(pl.BlockSpec((bd, bc), dw_index),
-                   pl.BlockSpec((1, bc), lambda j, p: (0, j))),
-        out_shape=(jax.ShapeDtypeStruct((dp, c), jnp.float32),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((bn, bc), jnp.float32),
-                        pltpu.VMEM((npad, bc), jnp.float32)],
-        compiler_params=_SEQUENTIAL2,
+        functools.partial(_sparse_fwd_body, bn, bc, bd, r, rp, b, bp, jp),
+        grid=(npad // bn, c // bc, dp // bd),
+        in_specs=[ell_spec, ell_spec, w_spec, b_spec, row_spec(rp)],
+        out_specs=(row_spec(1), row_spec(rp)),
+        out_shape=(jax.ShapeDtypeStruct((npad, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((npad, rp), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((bn, bc), jnp.float32)]
+        + [pltpu.VMEM((bn, rp), jnp.float32)] * 3,
+        compiler_params=_SEQUENTIAL3,
         interpret=interpret,
-        **_kernel_tags("sparse", kind),
-    )(colsp, valsp, wp, biasp, yp, lsep, gp)
+        **_kernel_tags("sparse", "fwd"),
+    )(colsp, valsp, wp, biasp, yp)
 
 
 def _check_sparse_shapes(cols, vals, w, bias, hashed_labels, num_buckets):
@@ -1061,34 +1145,139 @@ def _sparse_fwd(cols, vals, w, bias, hashed_labels, num_buckets, block_n,
     colsp, valsp, wp, biasp, yp, dp = _pad_sparse_operands(
         cols, vals, w, bias, hashed_labels, r, b, bn, rp, bp, bd, jp)
     dims = (colsp.shape[0], dp, r, rp, b, bp, rp * bp)
-    loss, lse = _sparse_call("fwd", colsp, valsp, wp, biasp, yp, None,
-                             None, dims, bn, bc, bd, jp, interpret)
+    loss, lse = _sparse_call(colsp, valsp, wp, biasp, yp, dims, bn, bc,
+                             bd, jp, interpret)
     return loss[:n, 0], (cols, vals, w, bias, hashed_labels, lse[:n])
+
+
+def _sorted_entries(cols, vals, d, bd, nkd):
+    """One backward call's ELL entries, sorted by feature -> (local
+    cols, rows, vals), each padded to an ``_ENTRY_CHUNK`` multiple, and
+    the work items of ``_work_items``.  The sort is stable, so a block's
+    entries keep their row order; slots whose col is outside [0, d)
+    (the ELL sentinel ``d``, val 0) sort past the last block."""
+    m, j = cols.shape
+    end = nkd * bd
+    key = jnp.where((cols >= 0) & (cols < d), cols, end).reshape(-1)
+    rows = jnp.broadcast_to(jnp.arange(m, dtype=jnp.int32)[:, None],
+                            (m, j)).reshape(-1)
+    key, rows, vals = jax.lax.sort(
+        (key.astype(jnp.int32), rows,
+         vals.astype(jnp.float32).reshape(-1)), num_keys=1, is_stable=True)
+    pad = round_up(m * j, _ENTRY_CHUNK) - m * j
+    key = jnp.pad(key, (0, pad), constant_values=end)
+    rows = jnp.pad(rows, (0, pad))
+    vals = jnp.pad(vals, (0, pad))
+    return key % bd, rows, vals, _work_items(key, bd, nkd)
+
+
+def _work_items(key, bd, nkd):
+    """(4, nkd + nchunks) int32 work items over the sorted keys, in d
+    block order (rows ``_BLK``, ``_CHUNK``, ``_LO``, ``_HI``).  An item
+    starts at each block's first entry and at each chunk's first entry,
+    and runs to the next item's start, so it lies in one block and one
+    chunk: every block has an item (an empty one when the block has no
+    entries) and a block's entries span as many items as chunks.
+    Ranges stop at the last entry inside d.  The searches compare every
+    key with every bound, (nkd + 1) x entries compares, so that the
+    step holds no gather (XLA rewrites one into ops without the phase
+    tag) and no device loop (the default binary search is one)."""
+    nchunks = key.shape[0] // _ENTRY_CHUNK
+    bounds = jnp.arange(nkd + 1, dtype=jnp.int32) * bd
+    starts = jnp.searchsorted(key, bounds, method="compare_all")
+    inner, stop = starts[1:nkd], starts[nkd:]
+    chunk_pos = jnp.arange(nchunks, dtype=jnp.int32) * _ENTRY_CHUNK
+    chunk_blk = jnp.searchsorted(inner, chunk_pos, side="right",
+                                 method="compare_all")
+    pos, blk = jax.lax.sort(
+        (jnp.concatenate([starts[:nkd], jnp.minimum(chunk_pos, stop)]),
+         jnp.concatenate([jnp.arange(nkd, dtype=jnp.int32), chunk_blk])),
+        num_keys=2)
+    nxt = jnp.concatenate([pos[1:], stop])
+    chunk = jnp.minimum(pos // _ENTRY_CHUNK, nchunks - 1)
+    base = chunk * _ENTRY_CHUNK
+    return jnp.concatenate([blk, chunk, pos - base, nxt - base]).reshape(
+        4, -1)
+
+
+def _sorted_bwd_call(cols, vals, wp, biasp, yp, lsep, gp, d, r, b, bc, bd,
+                     bp, interpret):
+    """One sparse backward call over ``cols.shape[0]`` rows -> (dW (d,
+    C), dbias (1, C)); ``yp``/``lsep``/``gp`` are padded to a multiple
+    of 8 rows."""
+    c = wp.shape[1]
+    rows = yp.shape[0]
+    nkd = pl.cdiv(d, bd)
+    lc, er, ev, items = _sorted_entries(cols, vals, d, bd, nkd)
+    g = items.shape[1]
+
+    def item(p):
+        return jnp.where(p >= g, p - g, p)
+
+    def w_index(j, p, it):
+        return it[_BLK, jnp.minimum(p, g - 1)], j
+
+    def dw_index(j, p, it):
+        return it[_BLK, item(p)] * (p >= g), j
+
+    entry_spec = pl.BlockSpec((_ENTRY_CHUNK,),
+                              lambda j, p, it: (it[_CHUNK, item(p)],),
+                              memory_space=pltpu.SMEM)
+    whole = lambda width: pl.BlockSpec((rows, width),
+                                       lambda j, p, it: (0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(c // bc, 2 * g),
+        in_specs=[entry_spec, entry_spec, entry_spec,
+                  pl.BlockSpec((bd, bc), w_index),
+                  pl.BlockSpec((1, bc), lambda j, p, it: (0, j)),
+                  whole(yp.shape[1]), whole(lsep.shape[1]), whole(1)],
+        out_specs=(pl.BlockSpec((bd, bc), dw_index),
+                   pl.BlockSpec((1, bc), lambda j, p, it: (0, j))),
+        scratch_shapes=[pltpu.VMEM((rows, bc), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_sorted_bwd_body, g, rows, bc, r, b, bp),
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((d, c), jnp.float32),
+                   jax.ShapeDtypeStruct((1, c), jnp.float32)),
+        compiler_params=_SEQUENTIAL2,
+        interpret=interpret,
+        **_kernel_tags("sparse", "bwd_sorted"),
+    )(items, lc, er, ev, wp, biasp, yp, lsep, gp)
 
 
 @phase.tagged(phase.LOSS_BWD)
 def _sparse_bwd(num_buckets, block_n, block_c, block_d, interpret, res, g):
+    """Feature-sorted backward (``_sorted_bwd_body``) over the forward's
+    column blocks and padded W, which XLA then builds once for both."""
     cols, vals, w, bias, hashed_labels, lse = res
     n, d, r, j = _check_sparse_shapes(cols, vals, w, bias, hashed_labels,
                                       num_buckets)
     b = num_buckets
     bn, bc, bd, rp, bp, jp = choose_sparse_blocks(n, d, r, b, j, block_n,
                                                   block_c, block_d)
-    colsp, valsp, wp, biasp, yp, dp = _pad_sparse_operands(
+    _, _, wp, biasp, yp, _ = _pad_sparse_operands(
         cols, vals, w, bias, hashed_labels, r, b, bn, rp, bp, bd, jp)
-    npad = colsp.shape[0]
-    dims = (npad, dp, r, rp, b, bp, rp * bp)
+    # the kernel loads single W rows, which Mosaic does only for 32-bit
+    # dtypes (16-bit ones pack two rows per sublane); for a 16-bit W
+    # this writes an f32 copy of the padded W to HBM (d x C x 4 bytes)
+    wp = wp.astype(jnp.float32)
+    bd, rows = choose_sorted_bwd_blocks(n, d, bc, rp, block_d)
+    npad = yp.shape[0]
+    # padded rows carry zero cotangent -> zero dlogits
     gp = jnp.pad(g.astype(jnp.float32).reshape(n, 1),
                  ((0, npad - n), (0, 0)))
     lsep = jnp.pad(lse, ((0, npad - n), (0, 0)))
-    parts = [_sparse_call("bwd", colsp[sl], valsp[sl], wp, biasp, yp[sl],
-                          lsep[sl], gp[sl], (sl.stop - sl.start,) + dims[1:],
-                          bn, bc, bd, jp, interpret)
-             for sl in _row_chunks(npad, _bwd_rows(n, bn))]
+    parts = []
+    for s in range(0, n, rows):     # rows is a multiple of 8
+        e, sl = min(s + rows, n), slice(s, s + min(rows, round_up(n - s, 8)))
+        parts.append(_sorted_bwd_call(
+            cols[s:e], vals[s:e], wp, biasp, yp[sl], lsep[sl], gp[sl], d,
+            r, b, bc, bd, bp, interpret))
     dwp = sum(dw for dw, _ in parts)
     dbp = sum(db for _, db in parts)
-    dw = dwp.reshape(dp, rp, bp)[:d, :r, :b].reshape(d, r * b) \
-        .astype(w.dtype)
+    dw = dwp.reshape(d, rp, bp)[:, :r, :b].reshape(d, r * b).astype(w.dtype)
     # features are data: zero cotangent for vals, none for int cols/labels
     db = (None if bias is None
           else dbp.reshape(rp, bp)[:r, :b].reshape(r * b)

@@ -366,10 +366,13 @@ def mach_fused_xent_csr(indptr: jnp.ndarray, indices: jnp.ndarray,
 
     On the Pallas path neither the (N, R·B) logits tensor nor a dense
     (N, d) activation ever exists in HBM in either pass — the batch is
-    re-laid-out as padded ELL (O(N·nnz_max)), and the VJP scatter-adds
-    dW (and reduces dbias) without a logits round-trip.  ``sparse_impl``
-    picks the kernel family: ``"densify"`` (per-tile one-hot
-    densification — the low-nnz fast path), ``"gather"`` (scalar-
+    re-laid-out as padded ELL (O(N·nnz_max)).  ``sparse_impl`` picks the
+    kernel family: ``"densify"`` (the low-nnz fast path: a forward that
+    densifies each d block's slice of the batch in VMEM, and a backward
+    that sorts the batch's entries by feature, rebuilds each row's
+    logits from the W rows its entries name, reading each W block once,
+    and writes each dW block once, from the entries that fall in it —
+    no logits round-trip, duplicate ids sum), ``"gather"`` (scalar-
     prefetch DMA of the active W rows — per-step VMEM independent of
     nnz, the only viable family at bag-of-words nnz), or ``None``
     (auto: gather at nnz_max >= GATHER_NNZ_THRESHOLD or whenever the

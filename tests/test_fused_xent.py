@@ -18,16 +18,18 @@ import numpy as np
 import pytest
 
 from repro.core.mach import MACHConfig, MACHOutputHead, mach_loss
-from repro.kernels import ops, ref
+from repro.kernels import mach_fused_xent, ops, ref
 from repro.kernels.mach_fused_xent import (DEFAULT_VMEM_BUDGET,
                                            GATHER_NNZ_THRESHOLD,
                                            choose_fused_blocks,
                                            choose_gather_blocks,
+                                           choose_sorted_bwd_blocks,
                                            choose_sparse_blocks,
                                            dense_tile_bytes,
                                            gather_tile_bytes,
                                            mach_fused_xent_gather_pallas,
                                            mach_fused_xent_pallas,
+                                           sorted_bwd_tile_bytes,
                                            sparse_tile_bytes)
 from repro.models import LanguageModel, ModelConfig
 
@@ -308,6 +310,24 @@ def test_choose_sparse_blocks_respects_budget(d, r, b, j):
     assert sparse_tile_bytes(bn, bc, bd, rp, jp) <= DEFAULT_VMEM_BUDGET
     assert bn % 8 == 0 and bd % 8 == 0 and jp % 128 == 0
     assert (rp * bp) % bc == 0 and rp >= r and bp >= b
+
+
+@pytest.mark.parametrize("n,d,r,b,j", [
+    (512, 422_713, 25, 32, 120),    # odp.train
+    (4096, 1024, 8, 4096, 64),      # the 500k-label selected-bucket job
+    (13, 96, 4, 16, 8),
+])
+def test_choose_sorted_bwd_blocks_respects_budget(n, d, r, b, j):
+    _, bc, _, rp, _, _ = choose_sparse_blocks(n, d, r, b, j)
+    bd, rows = choose_sorted_bwd_blocks(n, d, bc, rp)
+    assert sorted_bwd_tile_bytes(bc, bd, rp, rows) <= DEFAULT_VMEM_BUDGET
+    assert bd % 8 == 0 and rows % 8 == 0 and 8 <= rows <= -(-n // 8) * 8
+
+
+def test_sorted_bwd_chooser_raises_when_budget_impossible(monkeypatch):
+    monkeypatch.setattr(mach_fused_xent, "DEFAULT_VMEM_BUDGET", 100_000)
+    with pytest.raises(ValueError, match="100000 bytes of VMEM"):
+        choose_sorted_bwd_blocks(512, 422_713, 800, 25)
 
 
 def test_choosers_raise_when_budget_impossible():

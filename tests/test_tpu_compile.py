@@ -33,7 +33,9 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.lru_scan import lru_scan_pallas
 from repro.kernels.mach_candidates import mach_candidate_topk_pallas
 from repro.kernels.mach_decode import mach_decode_pallas
-from repro.kernels.mach_fused_xent import (mach_fused_xent_gather_pallas,
+from repro.kernels.mach_fused_xent import (choose_sorted_bwd_blocks,
+                                           choose_sparse_blocks,
+                                           mach_fused_xent_gather_pallas,
                                            mach_fused_xent_pallas,
                                            mach_fused_xent_sparse_pallas)
 from repro.kernels.mach_topk import mach_topk_pallas
@@ -78,13 +80,22 @@ def _compile(fn, sharding, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernels(text):
+    """(kernel name, ``mach_phase``) of each Mosaic custom call in a
+    compiled text; the name without the prefixes autodiff adds, the
+    phase "" where it has none."""
+    calls = [ins for ins in re.split(r"\n\s+(?=(?:ROOT )?%)", text)
+             if 'custom_call_target="tpu_custom_call"' in ins]
+    return [(re.sub(r"^(?:ROOT )?%(?:transpose_)?(?:jvp_)?|_*(?:\.\d+)? = .*",
+                    "", ins.split("\n")[0]),
+             m.group(1) if (m := re.search(r'"mach_phase":"([^"]+)"', ins))
+             else "") for ins in calls]
+
+
 def _kernel_phases(text):
     """The ``mach_phase`` of each Mosaic custom call in a compiled text
     ("" where it has none)."""
-    calls = [ins for ins in re.split(r"\n\s+(?=(?:ROOT )?%)", text)
-             if 'custom_call_target="tpu_custom_call"' in ins]
-    return [m.group(1) if (m := re.search(r'"mach_phase":"([^"]+)"', ins))
-            else "" for ins in calls]
+    return [p for _, p in _kernels(text)]
 
 
 # Entry ops that do no device work of their own, and the copies XLA
@@ -134,6 +145,29 @@ def test_sparse_fused_xent_odp(one_chip):
                     ((n, ODP_NNZ), jnp.int32), ((n, ODP_NNZ), jnp.float32),
                     ((ODP_D, ODP_R * ODP_B), jnp.float32),
                     ((ODP_R * ODP_B,), jnp.float32), ((n, ODP_R), jnp.int32))
+    _assert_kernel(text, "loss.fwd", "loss.bwd")
+    # the backward over feature-sorted entries, one kernel for both of
+    # its phases; the forward's blocks as the d-sweep backward left them
+    assert sorted(_kernels(text)) == [
+        ("mach_fused_xent_sparse_bwd_sorted", "loss.bwd"),
+        ("mach_fused_xent_sparse_fwd", "loss.fwd")]
+    assert choose_sparse_blocks(n, ODP_D, ODP_R, ODP_B, ODP_NNZ)[:3] == \
+        (8, 800, 256)
+    assert choose_sorted_bwd_blocks(n, ODP_D, 800, ODP_R) == (256, n)
+
+
+def test_sparse_fused_xent_bf16(one_chip):
+    """A bf16 head through the CSR loss: the backward's single-row
+    loads of W need it widened to f32 first."""
+    n, d, r, b, nnz = 64, 4096, 8, 64, 16
+
+    def loss(cols, vals, w, y):
+        return mach_fused_xent_sparse_pallas(cols, vals, w, None, y,
+                                             b).mean()
+
+    text = _compile(jax.value_and_grad(loss, argnums=2), one_chip,
+                    ((n, nnz), jnp.int32), ((n, nnz), jnp.bfloat16),
+                    ((d, r * b), jnp.bfloat16), ((n, r), jnp.int32))
     _assert_kernel(text, "loss.fwd", "loss.bwd")
 
 
@@ -289,10 +323,12 @@ def test_head_step_phases(one_chip, monkeypatch, features):
     tagged = compiled()
     assert set(re.findall(r'mach_phase="([\w.]+)"', tagged)) == {
         "loss.fwd", "loss.bwd", "optim"}
-    # every op but two carries its phase: XLA rebuilds the CSR->ELL
-    # gather of the column ids and carries no tag over
+    # every op but four carries its phase: XLA rebuilds the CSR->ELL
+    # gather of the column ids, and adds an index operand to each of the
+    # backward's two sorts, and carries no tag over
     assert _untagged_ops(tagged) == (
-        {"fusion": 1, "pad_clamp_fusion": 1} if features == "csr" else {})
+        {"fusion": 1, "pad_clamp_fusion": 1, "iota": 2}
+        if features == "csr" else {})
     monkeypatch.setattr(phase, "tag", lambda p: contextlib.nullcontext())
     monkeypatch.setattr(mach_fused_xent, "_kernel_tags", lambda *a: {})
     plain = compiled()
