@@ -7,15 +7,20 @@ that sets them (not run by a benchmark run).
     python3 bench/calibrate.py --workload <cell> --add-runs OUT...
     python3 bench/calibrate.py --workload <cell> --limits
 
-Readings, in one process at the cell's own sizes:
+Readings, in one process at the cell's own sizes, through the hooks of
+the cell's driver module (``drivers/<kind>.py``):
 
-* program: for each seed, the cell's set-up (and, for decode, a short
-  window at the cell's load) and the check, exactly as a run makes them;
-* control: the plain reference put in the program's place and computed
-  one precision below the configuration's (``bf16_3x``, three bfloat16
-  passes, for float32 at ``highest``), compared with the reference;
-* half_batch (training): the reference taking its loss over the first
-  half of each batch only, compared with the reference.
+* program: for each seed, the cell's set-up (and, where the driver's
+  ``CHECK_READS_WINDOW`` says the check compares a window's output, a
+  short window at the cell's load) and the check, exactly as a run
+  makes them;
+* control: the driver's ``readings``: the plain reference put in the
+  program's place and computed one precision below the configuration's
+  (``bf16_3x``, three bfloat16 passes, for float32 at ``highest``),
+  compared with the reference;
+* half_batch (where the driver's ``half_batch_rows`` gives rows): the
+  reference taking its loss over the first half of each batch only,
+  compared with the reference.
 
 They are added to ``limits/<cell>.readings.json``, as are, with
 ``--add-runs``, the numbers of benchmark runs (files whose last line is
@@ -46,47 +51,25 @@ for p in (ROOT, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from bench import compare, generate, spec  # noqa: E402
+from bench import spec  # noqa: E402
 
 
-def _inputs(cell, seed: int):
-    key = generate.seed_key(seed)
-    c, t = cell.config, cell.traffic
-    params = generate.make_params(
-        generate.stream(key, "weights"), dim=c["dim"],
-        reps=c["num_repetitions"], buckets=c["num_buckets"],
-        w_std=float(t["w_std"]), b_std=float(t["b_std"]))
-    n = t["examples_per_step"] if cell.kind == "train" \
-        else t["queries_per_batch"]
-    return params, generate.ring(generate.stream(key, "data"), c, t, n)
+def driver_module(cell):
+    return spec.load_module("drivers", cell.kind)
 
 
 def control_numbers(cell, seed: int, rows: int | None = None,
                     mode: str = "bf16_3x") -> dict:
     """The reference at ``mode`` (and over ``rows`` rows of each batch)
     in the program's place, compared with the reference."""
-    ref = spec.load_module("reference", cell.config["reference"])
-    params, batches = _inputs(cell, seed)
-    c, t = cell.config, cell.traffic
-    if cell.kind == "train":
-        k = t["check_steps"]
-        hi = ref.train(c, params, batches[:k])
-        lo = ref.train(c, params, batches[:k], mode, rows)
-        return compare.train_numbers(lo[0], hi[0], lo[1], hi[1], params,
-                                     lo[2], hi[2])
-    worst: dict = {}
-    for batch in batches[:t["check_batches"]]:
-        vals_r, _, scores_r = ref.topk(c, params, batch, t["k"])
-        vals_c, ids_c, _ = ref.topk(c, params, batch, t["k"], mode)
-        got = compare.decode_numbers(vals_c, ids_c, vals_r, scores_r)
-        worst = {k: max(v, worst.get(k, -1.0)) for k, v in got.items()}
-    return worst
+    return driver_module(cell).readings(cell, seed, mode, rows)
 
 
 def program_numbers(cell, seed: int, seconds: float) -> dict:
-    driver = spec.load_module("drivers", cell.kind).Driver(cell, seed)
+    mod = driver_module(cell)
+    driver = mod.Driver(cell, seed)
     driver.setup()
-    if cell.kind != "train":
+    if mod.CHECK_READS_WINDOW:
         driver.window(seconds)
     driver.release()
     return driver.check()
@@ -164,12 +147,12 @@ def measure(cell, seeds, control_seeds, seconds: float) -> dict:
         readings["program"][f"calibrate {s}"] = got
         print(f"program {s} {got}", file=sys.stderr, flush=True)
         gc.collect()
+    half = driver_module(cell).half_batch_rows(cell)
     for s in control_seeds:
         got = control_numbers(cell, s)
         readings["control"][str(s)] = got
         print(f"control {s} {got}", file=sys.stderr, flush=True)
-        if cell.kind == "train":
-            half = cell.traffic["examples_per_step"] // 2
+        if half is not None:
             got = control_numbers(cell, s, half, "highest")
             readings["half_batch"][str(s)] = got
             print(f"half_batch {s} {got}", file=sys.stderr, flush=True)

@@ -11,6 +11,11 @@ batch's latency (a query's latency is its batch's).
 
 After the window a sample of the finished batches, drawn from the seed,
 is compared with the reference: their top-k values and ids.
+
+Calibration (``bench/calibrate.py``): the check compares what a window
+produced; the control is the reference at a lower precision over the
+first ``check_batches`` batches of the ring.  Decode has no half-batch
+fault.
 """
 
 from __future__ import annotations
@@ -20,33 +25,59 @@ import time
 import jax
 import numpy as np
 
-from bench import compare, generate, program
+from bench import compare, generate
 from bench.spec import load_module
+
+CHECK_READS_WINDOW = True
+
+
+def _ring(cell, key):
+    return generate.ring(generate.stream(key, "data"), cell.config,
+                         cell.traffic, cell.traffic["queries_per_batch"])
+
+
+def half_batch_rows(cell) -> None:
+    return None
+
+
+def readings(cell, seed: int, mode: str, rows: int | None = None) -> dict:
+    """The reference at ``mode`` in the program's place over the first
+    ``check_batches`` batches, compared with the reference; the worst
+    batch counts."""
+    if rows is not None:
+        raise ValueError("decode has no half-batch fault")
+    ref = load_module("reference", cell.config["reference"])
+    key = generate.seed_key(seed)
+    params = generate.head_params(key, cell.config, cell.traffic)
+    batches = _ring(cell, key)
+    c, k = cell.config, cell.traffic["k"]
+    worst: dict = {}
+    for batch in batches[:cell.traffic["check_batches"]]:
+        vals_r, _, scores_r = ref.topk(c, params, batch, k)
+        vals_c, ids_c, _ = ref.topk(c, params, batch, k, mode)
+        got = compare.decode_numbers(vals_c, ids_c, vals_r, scores_r)
+        worst = {n: max(v, worst.get(n, -1.0)) for n, v in got.items()}
+    return worst
 
 
 class Driver:
 
     def __init__(self, cell, seed: int):
+        self.cell = cell
         self.config, self.traffic = cell.config, cell.traffic
         self.seed = seed
         self.n = self.traffic["queries_per_batch"]
         self.k = self.traffic["k"]
         self.key = generate.seed_key(seed)
-
-    def _params(self):
-        c, t = self.config, self.traffic
-        return generate.make_params(
-            generate.stream(self.key, "weights"), dim=c["dim"],
-            reps=c["num_repetitions"], buckets=c["num_buckets"],
-            w_std=float(t["w_std"]), b_std=float(t["b_std"]))
+        self.program = load_module("programs", self.config["model"])
 
     def setup(self) -> None:
-        c = self.config
+        c, program = self.config, self.program
         model = program.head(c)
         self.meta, self.topk, self.table = program.decode_calls(model, self.k)
-        self.params = self._params()
-        self.batches = generate.ring(generate.stream(self.key, "data"), c,
-                                     self.traffic, self.n)
+        self.params = generate.head_params(self.key, self.config,
+                                           self.traffic)
+        self.batches = _ring(self.cell, self.key)
         self.inputs = [program.inputs(c, b)[0] for b in self.batches]
         for x in self.inputs[:2]:
             jax.block_until_ready(self.topk(self.meta(self.params, x),
@@ -96,7 +127,8 @@ class Driver:
 
     def check(self) -> dict:
         ref = load_module("reference", self.config["reference"])
-        params = self._params()
+        params = generate.head_params(self.key, self.config,
+                                      self.traffic)
         worst: dict = {}
         for i in self.sample():
             vals_r, _, scores_r = ref.topk(

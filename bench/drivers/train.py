@@ -12,6 +12,11 @@ entry with the same call, steps dispatched back to back and at most
 two in flight.  ``train_examples_per_s`` is the examples of every step
 dispatched in the window over the time from its start to the end of the
 last of them.
+
+Calibration (``bench/calibrate.py``): the check reads set-up's steps, so
+no window is needed; the control is the reference at a lower precision
+over the same steps, and the half-batch fault the reference taking its
+loss over the first half of each batch.
 """
 
 from __future__ import annotations
@@ -22,36 +27,55 @@ import time
 import jax
 import numpy as np
 
-from bench import compare, generate, program
+from bench import compare, generate
 from bench.spec import load_module
 
 IN_FLIGHT = 2
+CHECK_READS_WINDOW = False
+
+
+def _ring(cell, key):
+    return generate.ring(generate.stream(key, "data"), cell.config,
+                         cell.traffic, cell.traffic["examples_per_step"])
+
+
+def half_batch_rows(cell) -> int:
+    return cell.traffic["examples_per_step"] // 2
+
+
+def readings(cell, seed: int, mode: str, rows: int | None = None) -> dict:
+    """The reference at ``mode``, over ``rows`` rows of each batch (all
+    where None), in the program's place over the checked steps, compared
+    with the reference."""
+    ref = load_module("reference", cell.config["reference"])
+    key = generate.seed_key(seed)
+    params = generate.head_params(key, cell.config, cell.traffic)
+    batches = _ring(cell, key)[:cell.traffic["check_steps"]]
+    hi = ref.train(cell.config, params, batches)
+    lo = ref.train(cell.config, params, batches, mode, rows)
+    return compare.train_numbers(lo[0], hi[0], lo[1], hi[1], params, lo[2],
+                                 hi[2])
 
 
 class Driver:
 
     def __init__(self, cell, seed: int):
+        self.cell = cell
         self.config, self.traffic = cell.config, cell.traffic
         self.seed = seed
         self.n = self.traffic["examples_per_step"]
         self.key = generate.seed_key(seed)
-
-    def _params(self):
-        c, t = self.config, self.traffic
-        return generate.make_params(
-            generate.stream(self.key, "weights"), dim=c["dim"],
-            reps=c["num_repetitions"], buckets=c["num_buckets"],
-            w_std=float(t["w_std"]), b_std=float(t["b_std"]))
+        self.program = load_module("programs", self.config["model"])
 
     def setup(self) -> None:
-        c = self.config
+        c, program = self.config, self.program
         model = program.head(c)
         opt = program.optimizer(c)
         self.step = program.train_step(model, opt)
-        params = self._params()
+        params = generate.head_params(self.key, self.config,
+                                      self.traffic)
         state = jax.jit(opt.init)(params)
-        self.batches = generate.ring(generate.stream(self.key, "data"), c,
-                                     self.traffic, self.n)
+        self.batches = _ring(self.cell, self.key)
         if len(self.batches) <= self.traffic["check_steps"]:
             raise ValueError("the ring must outlast the checked steps")
         self.inputs = [program.inputs(c, b) for b in self.batches]
@@ -107,7 +131,7 @@ class Driver:
     def check(self) -> dict:
         """The reference over the checked steps, and the numbers."""
         ref = load_module("reference", self.config["reference"])
-        p0 = self._params()
+        p0 = generate.head_params(self.key, self.config, self.traffic)
         k = self.traffic["check_steps"]
         losses, grad, after = ref.train(self.config, p0, self.batches[:k])
         return compare.train_numbers(self.losses, losses, self.grad, grad,

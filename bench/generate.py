@@ -64,6 +64,15 @@ def make_params(key, *, dim: int, reps: int, buckets: int, w_std: float,
             "b": b_std * jax.random.normal(kb, (reps, buckets), jnp.float32)}
 
 
+def head_params(key: jax.Array, config: dict, traffic: dict) -> dict:
+    """The cell's MACH-linear weights, from the seed's weights stream."""
+    return make_params(stream(key, "weights"), dim=config["dim"],
+                       reps=config["num_repetitions"],
+                       buckets=config["num_buckets"],
+                       w_std=float(traffic["w_std"]),
+                       b_std=float(traffic["b_std"]))
+
+
 @functools.partial(jax.jit, static_argnames=("classes", "dim", "n", "ring",
                                              "zipf_a", "noise"))
 def dense_ring(key, *, classes: int, dim: int, n: int, ring: int,

@@ -1,8 +1,10 @@
-"""The system under test, built from a configuration file.
+"""The system under test for configurations whose "model" is
+``mach_linear``, built from the configuration file.
 
-Everything the benchmark takes from the program (``src/repro``) goes
-through here: the MACH head, its optimizer and jitted train step, the
-jitted decode calls, and the program's own input type for CSR batches.
+Everything the ``train`` and ``decode`` drivers take from the program
+(``src/repro``) goes through here: the MACH head, its optimizer and
+jitted train step, the jitted decode calls, and the program's own input
+type for CSR batches.
 """
 
 from __future__ import annotations

@@ -4,12 +4,13 @@ A roofline share is the least time the chip could take for a kernel's
 work (the larger of its operations over the peak rate and its bytes
 over the peak bandwidth) over the time the kernel took in the trace.
 A model-FLOP utilization is the operations the model needs per item,
-times items per second, over the peak rate.
+times items per second, over the peak rate.  Beside them, the device
+time of one program phase per call.
 """
 
 from __future__ import annotations
 
-from bench.spec import load_module
+from bench.spec import ABSENT, load_module
 
 
 def work(kernel: str, facts) -> dict:
@@ -45,3 +46,15 @@ def idle(facts):
     if facts.trace is None or facts.trace.window_s <= 0:
         return None
     return 100.0 * (1.0 - facts.trace.busy_s / facts.trace.window_s)
+
+
+def phase_ms(phase: str, modules: tuple[str, ...], facts):
+    """Device ms per call of the ops tagged ``phase`` (``""``: untagged)
+    inside the jitted programs ``modules`` (``trace.Summary.phase_ms``);
+    ABSENT where the program tags no op (one older than the tags), None
+    where none of ``modules`` ran."""
+    if facts.trace is None:
+        return None
+    if not facts.trace.tagged:
+        return ABSENT
+    return facts.trace.phase_ms(phase, modules)

@@ -95,12 +95,16 @@ def device_info(chips: int) -> dict:
 
 def per_layer(cell, facts) -> dict:
     """Every per-layer metric of the cell; a reader returns a number or a
-    dict with "value" and notes beside it.  A reader that finds nothing
+    dict with "value" and notes beside it.  One that returns
+    ``spec.ABSENT`` (the program has nothing it reads, such as phase tags
+    or a counter) is left out of the line.  A reader that finds nothing
     in a cell that lists its metric (a kernel or program renamed out of
-    its sight) ends the run without a result."""
+    its sight) returns None and ends the run without a result."""
     out, missing = {}, []
     for m in cell.per_layer:
         got = spec.load_module("metrics", m["name"]).read(facts)
+        if got is spec.ABSENT:
+            continue
         if got is None:
             missing.append(m["name"])
             continue
@@ -158,6 +162,7 @@ def execute(cell, seed: int, seconds: float, trace: bool, t0: float
     if trace:
         facts = types.SimpleNamespace(
             config=cell.config, traffic=cell.traffic, trace=summary,
+            counters=res.get("counters", {}),
             items=res["items"], window_s=res["seconds"],
             peak=peak_of(device["kind"]) if device["platform"] == "tpu"
             else None)

@@ -3,17 +3,44 @@
 ``BENCHMARK.json`` names cells, configurations, traffic mixes and
 metrics; each piece is a file of its own under ``bench/``:
 
-    configs/<config>.json      sizes of one configuration (+ its source)
+    configs/<config>.json      sizes of one configuration (+ its source;
+                               a configuration cut to one chip's share
+                               gives the published value of each key in
+                               its BENCHMARK.json "reduced" list under
+                               "published", and its "deployment")
+    programs/<model>.py        the system under test, built from a
+                               configuration whose "model" names it
     traffic/<traffic>.json     parameters of one traffic mix; its "kind"
                                names the driver drivers/<kind>.py
+    drivers/<kind>.py          set-up, window and check of one kind, and
+                               its calibration hooks (calibrate.py)
     reference/<name>.py        the plain reference a configuration names
     metrics/<metric>.py        the reader of one per-layer metric
     work/<kernel>.py           operations and bytes of one kernel
     limits/<cell>.json         the limits of the comparison that decides
                                `correct` in one cell
+    tiny/<cell>.json           configuration and traffic overrides that
+                               make one cell small enough for the CPU tests
 
-A later cell, mix or metric is added by adding files and entries; no
-file here has to change.
+A driver module holds ``Driver(cell, seed)`` with ``setup()``,
+``window(seconds)``, ``release()`` and ``check()``.  ``window`` returns
+"attempted", "failed", "items", "seconds", "longest_s", the end-to-end
+"metrics", and may return "counters": {name: number}, the program's
+counts summed over the window's steps, which the per-layer readers get
+as ``facts.counters``.  Beside it: ``readings(cell, seed, mode, rows)``
+(the reference at ``mode``, over ``rows`` rows of each batch, in the
+program's place, compared with the reference), ``CHECK_READS_WINDOW``
+(whether ``check`` compares what the window produced) and
+``half_batch_rows(cell)`` (the rows of the half-batch fault, or None
+where the kind has none).
+
+A metric reader's ``read(facts)`` returns a number, a dict with "value",
+``ABSENT`` where the run has nothing for it to read (a program older
+than what it reads), which leaves the metric out of the line, or None
+where it should have found something, which ends the run.
+
+A later cell, mix, configuration or metric is added by adding files and
+entries; no file here has to change.
 """
 
 from __future__ import annotations
@@ -27,6 +54,14 @@ from typing import Any
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
+
+
+class _Absent:
+    def __repr__(self) -> str:
+        return "ABSENT"
+
+
+ABSENT = _Absent()  # what a metric reader returns with nothing to read
 
 
 def load_json(path: pathlib.Path) -> Any:
@@ -88,3 +123,12 @@ def resolve(name: str, bench_json: pathlib.Path = ROOT / "BENCHMARK.json"
         end_to_end=[m for m in spec["end_to_end"] if applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if applies(m, name)],
         limits=limits)
+
+
+def tiny(cell: Cell) -> Cell:
+    """The cell at the sizes of ``tiny/<cell>.json``, for runs on the
+    CPU."""
+    over = load_json(BENCH / "tiny" / f"{cell.name}.json")
+    return dataclasses.replace(
+        cell, config=dict(cell.config, **over["config"]),
+        traffic=dict(cell.traffic, **over["traffic"]))

@@ -2,7 +2,9 @@
 CPU, through the program's own CPU path: a sound run is correct under
 the cell's limits; the timed path broken underneath, or the reference
 put in the program's place one precision lower (the control), is not.
-The harness's look for a chip is skipped by calling ``run.execute``."""
+The harness's look for a chip is skipped by calling ``run.execute``.
+Every workload of ``BENCHMARK.json`` is run at the sizes of its
+``tiny/<cell>.json``."""
 
 import os
 import subprocess
@@ -16,36 +18,22 @@ import pytest
 from bench import calibrate, spec
 from bench.run import execute
 
-TINY = {
-    "odp.train": ({"num_classes": 500, "dim": 2048, "nnz": 16},
-                  {"examples_per_step": 32, "ring": 4}),
-    "imagenet21k.train": ({"num_classes": 500, "dim": 128,
-                           "num_buckets": 32, "num_repetitions": 4},
-                          {"examples_per_step": 32, "ring": 4}),
-    "odp.decode": ({"num_classes": 500, "dim": 2048, "nnz": 16},
-                   {"queries_per_batch": 16, "ring": 4,
-                    "check_batches": 3}),
-    "imagenet21k.decode": ({"num_classes": 500, "dim": 128,
-                            "num_buckets": 32, "num_repetitions": 4},
-                           {"queries_per_batch": 16, "ring": 4,
-                            "check_batches": 3}),
-}
+CELLS = sorted(w["name"] for w in
+               spec.load_json(spec.ROOT / "BENCHMARK.json")["workloads"])
 SEED = 2**33 + 7
 
 
 def tiny(name):
-    c = spec.resolve(name)
-    config, traffic = TINY[name]
-    return spec.Cell(name, 1, dict(c.config, **config),
-                     dict(c.traffic, **traffic), c.end_to_end, c.per_layer,
-                     c.limits)
+    """The cell at the sizes of tiny/<cell>.json; a cell without that
+    file fails here."""
+    return spec.tiny(spec.resolve(name))
 
 
 def run(cell):
     return execute(cell, SEED, 0.3, False, time.perf_counter())
 
 
-@pytest.mark.parametrize("name", sorted(TINY))
+@pytest.mark.parametrize("name", CELLS)
 def test_sound_run_is_correct(name, highest_precision):
     out = run(tiny(name))
     assert out["correct"], out["checks"]
@@ -56,7 +44,7 @@ def test_sound_run_is_correct(name, highest_precision):
     assert list(out)[-1] == "checks"
 
 
-@pytest.mark.parametrize("name", sorted(TINY))
+@pytest.mark.parametrize("name", CELLS)
 def test_control_is_not_correct(name, highest_precision):
     """The reference at bf16_3x in the program's place fails a limit."""
     cell = tiny(name)

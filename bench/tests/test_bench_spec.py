@@ -55,12 +55,35 @@ def test_cell_resolves(cell):
     assert c.limits["numbers"], f"no limits for {cell}"
 
 
+# a width may never be cut: a hidden, intermediate, latent, state or
+# projection size, a head size, an expansion factor, experts per token
+WIDTH = re.compile(r"(_dim$|_rank$|^dim$|^d_model$|hidden_size|"
+                   r"intermediate|latent|state_size|d_state|proj|head_size|"
+                   r"head_dim|expan|per_tok|top_?k)", re.IGNORECASE)
+
+
 @pytest.mark.parametrize("config", SPEC["configs"], ids=lambda c: c["name"])
 def test_config_file(config):
+    """A configuration cut to one chip's share (the model-configs guide,
+    section 4) names each key it changed under "reduced" in
+    BENCHMARK.json; its file holds each such key, its published value
+    (which differs) under "published", and a "deployment" that says over
+    how many chips each layer is divided, and how.  No width is cut."""
     data = spec.load_json(spec.ROOT / config["file"])
     assert data["name"] == config["name"]
     assert config["file"].startswith("bench/configs/")
-    assert config["reduced"] == []
+    reduced = config["reduced"]
+    assert len(reduced) == len(set(reduced)) <= 16
+    published = data.get("published", {})
+    for key in reduced:
+        assert NAME.match(key) and not WIDTH.search(key), key
+        assert key in data and key in published, key
+        assert published[key] != data[key], key
+    assert set(published) <= set(reduced)
+    if reduced:
+        assert isinstance(data["deployment"], str)
+        assert re.search(r"\d+ chips", data["deployment"]), \
+            "deployment: over how many chips each layer is divided"
     for key in ("num_classes", "dim", "num_buckets", "num_repetitions"):
         assert isinstance(data[key], int) and data[key] > 0
 
